@@ -84,6 +84,7 @@ from repro.fl import (
 from repro.fl.runtime import masked_accuracy
 from repro.models import cnn
 from repro.obs import ObsConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 OUT = Path(__file__).resolve().parents[1] / "experiments" / "bench"
 
@@ -1077,6 +1078,7 @@ def main():
                     default="phase",
                     help="instrumentation depth for --trace-dir runs")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace_dir:
         OBS_CFG.update(trace_dir=args.trace_dir, level=args.obs_level)
 

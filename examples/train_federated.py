@@ -73,6 +73,7 @@ from repro.fl.runtime import masked_accuracy
 from repro.models import cnn
 from repro.obs import ObsConfig
 from repro.utils.checkpoint import latest_step, save_checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_method(name, lr, args):
@@ -105,8 +106,10 @@ def main():
     ap.add_argument("--participation", type=float, default=0.2)
     ap.add_argument("--rounds", type=int, default=15)
     ap.add_argument("--samples", type=int, default=4000)
-    ap.add_argument("--classes", type=int, default=10)
-    ap.add_argument("--image-size", type=int, default=16)
+    ap.add_argument("--classes", type=int, default=None,
+                    help="label classes (default: the --model config's)")
+    ap.add_argument("--image-size", type=int, default=None,
+                    help="input height = width (default: the --model config's)")
     ap.add_argument("--batch", type=int, default=50)  # paper batch size
     ap.add_argument("--lr", type=float, default=0.05)
     ap.add_argument("--rho", type=float, default=1.0)
@@ -235,6 +238,7 @@ def main():
                     help="legacy: save only the final broadcast per method")
     ap.add_argument("--tag", default="run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
@@ -295,6 +299,10 @@ def main():
         args.samples = 20000
 
     cfg = SMALL_CNN if args.model == "small" else RESNET9_CIFAR100
+    if args.classes is None:
+        args.classes = cfg.n_classes
+    if args.image_size is None:
+        args.image_size = cfg.cnn_image_size
     cfg = cfg.replace(n_classes=args.classes, cnn_image_size=args.image_size)
 
     print(f"dataset: {args.samples} samples, {args.classes} classes, "

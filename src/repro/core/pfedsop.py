@@ -196,8 +196,8 @@ def _fused_flat_update(eta1, rho, lam, eps, interpret, shard=None):
     (``repro.kernels.dispatch.model_shard_axis``, DESIGN.md §11): both the
     primal and the batched rule then take the model-sharded kernel layout,
     which splits the flattened-N tile rows over the mesh axis and combines
-    the three Gompertz scalars with a cross-shard psum — bit-identical to
-    the unsharded kernel.
+    the three Gompertz scalars' per-tile partials with a cross-shard
+    all-gather — bit-identical to the unsharded kernel.
     """
     from repro.kernels.pfedsop_update.ops import (
         pfedsop_update,

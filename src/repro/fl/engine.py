@@ -14,9 +14,9 @@ Three interchangeable backends ship today:
                    device vmaps its local client slice.  Within a pod the
                    per-client phase replicates over `(data, model)` —
                    except the §9 round-start update, whose flattened-N
-                   axis shards over ``model`` (per-shard partial
-                   reductions + cross-shard psum for the three Gompertz
-                   scalars; `repro.kernels.pfedsop_update`).  In specs
+                   axis shards over ``model`` (per-tile partial
+                   reductions + cross-shard all-gather for the three
+                   Gompertz scalars; `repro.kernels.pfedsop_update`).  In specs
                    come from the composed pspec helpers
                    (`launch/sharding.py::client_stacked_pspecs`), so
                    Megatron-eligible leaves of transformer-family state
@@ -46,10 +46,7 @@ from typing import Any, Callable, Optional, Protocol, Union, runtime_checkable
 
 import jax
 
-try:  # moved out of jax.experimental in newer jax releases
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -318,10 +315,11 @@ class MeshBackend:
                 local_trees[0], broadcast_, *local_trees[1:]
             )
 
-        # check_rep=False: jax has no replication rule for pallas_call, so
-        # the rep checker rejects the kernel update impl (DESIGN.md §9).
-        # Safe here — outputs are re-constrained to replicated at the round
-        # boundary (``replicate``), so the check would not tighten anything.
+        # check_vma=False: jax has no varying-manual-axes rule for
+        # pallas_call, so the checker rejects the kernel update impl
+        # (DESIGN.md §9).  Safe here — outputs are re-constrained to
+        # replicated at the round boundary (``replicate``), so the check
+        # would not tighten anything.
         msize = self.spec.model_size
         with contextlib.ExitStack() as ctx:
             if self.spec.model_axis is not None and msize > 1:
@@ -335,7 +333,7 @@ class MeshBackend:
                 mesh=self.mesh,
                 in_specs=(P(),) + specs,
                 out_specs=out_spec,
-                check_rep=False,
+                check_vma=False,
             )(broadcast, *in_trees)
         return self.replicate(out) if replicated else out
 
@@ -407,7 +405,7 @@ class MeshBackend:
             mesh=self.mesh,
             in_specs=(P(),) + specs,
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(broadcast, *upload_trees)
 
     def eval_phase(self, one_eval, states, broadcast, test_sets):

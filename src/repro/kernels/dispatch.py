@@ -120,7 +120,7 @@ def resolve_impl(impl: str, kernel: str) -> str:
 #
 #   model_shard_axis   kernels with a model-sharded layout (pfedsop_
 #                      update's flattened-N axis) split their sweep —
-#                      per-shard partials + cross-shard psum.
+#                      per-tile partials + cross-shard all-gather.
 #   client_shard_axis  the sharded aggregation program (§11 output-
 #                      sharding): cohort reductions (``repro.optim.
 #                      reduce.cohort_mean``/``cohort_sum``) combine

@@ -169,8 +169,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, window,
             # second online-softmax sweep.  Causal masking guarantees at
             # least one valid key per row (k = q), so l > 0 always; the
             # where() mirrors the output guard for safety.
-            lse_ref[0] = (m_scr[...] +
-                          jnp.log(jnp.where(l == 0.0, 1.0, l)))[:, 0]
+            lse_ref[0] = m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
 
 def flash_gqa_pallas(q, k, v, window=None, softcap=None, scale=None,
@@ -181,6 +180,10 @@ def flash_gqa_pallas(q, k, v, window=None, softcap=None, scale=None,
     With ``return_residual`` also emits the per-row log-sum-exp
     (B,H,S) f32 — the forward residual the fused backward kernels need
     to recompute probabilities without a second online-softmax sweep.
+    Inside the kernels such row vectors carry a trailing unit dim, (B*H,
+    S, 1), so each (1, BQ, 1) block is legal TPU tiling (BQ a multiple of
+    8, 1 the full minor dim) and is already the (BQ, 1) column the
+    kernels broadcast against.
     """
     b, h, s, d = q.shape
     kv = k.shape[1]
@@ -211,9 +214,9 @@ def flash_gqa_pallas(q, k, v, window=None, softcap=None, scale=None,
     out_shape = jax.ShapeDtypeStruct((b * h, s, d), q.dtype)
     if return_residual:
         out_specs = (out_specs,
-                     pl.BlockSpec((1, bq), lambda bh, qi, j: (bh, qi)))
+                     pl.BlockSpec((1, bq, 1), lambda bh, qi, j: (bh, qi, 0)))
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b * h, s), jnp.float32))
+                     jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -289,11 +292,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = s_raw
     s = jnp.where(_mask_block(qi, ki, bq, bk, window), s, NEG_INF)
 
-    p = jnp.exp(s - lse_ref[0][:, None])  # masked entries -> exp(-inf) = 0
+    p = jnp.exp(s - lse_ref[0])  # masked entries -> exp(-inf) = 0
     do = do_ref[0].astype(jnp.float32)  # (BQ, D)
     dp = jnp.dot(do, v_ref[0, 0].astype(jnp.float32).T,
                  preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0])
     if softcap is not None:
         ds = ds * (1.0 - t * t)
     dq_scr[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
@@ -329,11 +332,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = s_raw
     s = jnp.where(_mask_block(qi, ki, bq, bk, window), s, NEG_INF)
 
-    p = jnp.exp(s - lse_ref[0][:, None])  # (BQ, BK)
+    p = jnp.exp(s - lse_ref[0])  # (BQ, BK)
     do = do_ref[0].astype(jnp.float32)  # (BQ, D)
     dp = jnp.dot(do, v_ref[0, 0].astype(jnp.float32).T,
                  preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0])
     if softcap is not None:
         ds = ds * (1.0 - tc * tc)
 
@@ -367,14 +370,14 @@ def flash_gqa_bwd_pallas(q, k, v, out, lse, do, window=None, softcap=None,
     pruned = nkp < nk
 
     # delta = rowsum(dO * O): O(S*D) elementwise work, done once outside
-    # the kernels so both passes read a precomputed (B*H, S) vector.
+    # the kernels so both passes read a precomputed (B*H, S, 1) column.
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1, keepdims=True)
 
     qf = q.reshape(b * h, s, d)
     dof = do.reshape(b * h, s, d)
-    lsef = lse.reshape(b * h, s)
-    deltaf = delta.reshape(b * h, s)
+    lsef = lse.reshape(b * h, s, 1)
+    deltaf = delta.reshape(b * h, s, 1)
 
     # --- dq pass: the forward's grid, accumulating over KV blocks -------
     if pruned:
@@ -389,7 +392,7 @@ def flash_gqa_bwd_pallas(q, k, v, out, lse, do, window=None, softcap=None,
         return (bh, qi, 0)
 
     def row_index(bh, qi, j):
-        return (bh, qi)
+        return (bh, qi, 0)
 
     dq = pl.pallas_call(
         functools.partial(
@@ -402,8 +405,8 @@ def flash_gqa_bwd_pallas(q, k, v, out, lse, do, window=None, softcap=None,
             pl.BlockSpec((1, 1, bk, d), kv_index),
             pl.BlockSpec((1, 1, bk, d), kv_index),
             pl.BlockSpec((1, bq, d), q_index),
-            pl.BlockSpec((1, bq), row_index),
-            pl.BlockSpec((1, bq), row_index),
+            pl.BlockSpec((1, bq, 1), row_index),
+            pl.BlockSpec((1, bq, 1), row_index),
         ],
         out_specs=pl.BlockSpec((1, bq, d), q_index),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
@@ -428,7 +431,7 @@ def flash_gqa_bwd_pallas(q, k, v, out, lse, do, window=None, softcap=None,
         return (bh_index(bkv, t), bwd_q_block(ki, t), 0)
 
     def rowd_index(bkv, ki, t):
-        return (bh_index(bkv, t), bwd_q_block(ki, t))
+        return (bh_index(bkv, t), bwd_q_block(ki, t), 0)
 
     def kvd_index(bkv, ki, t):
         return (bkv // kv, bkv % kv, ki, 0)
@@ -444,8 +447,8 @@ def flash_gqa_bwd_pallas(q, k, v, out, lse, do, window=None, softcap=None,
             pl.BlockSpec((1, 1, bk, d), kvd_index),
             pl.BlockSpec((1, 1, bk, d), kvd_index),
             pl.BlockSpec((1, bq, d), qd_index),
-            pl.BlockSpec((1, bq), rowd_index),
-            pl.BlockSpec((1, bq), rowd_index),
+            pl.BlockSpec((1, bq, 1), rowd_index),
+            pl.BlockSpec((1, bq, 1), rowd_index),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, bk, d), kvd_index),

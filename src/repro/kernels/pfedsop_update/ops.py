@@ -1,14 +1,15 @@
 """Public wrapper: fused pFedSOP round-start update.
 
 ``pfedsop_update(x, delta_i, delta_g, ...)`` takes flat parameter vectors
-(any float dtype), pads to (rows, 128) tiles, runs the two-phase kernel and
-returns (x_new, beta).  ``pfedsop_update_batched`` is the same update with
-a leading participating-client axis — (C, N) operands, (C,) betas — backed
-by the (clients, tiles) grid kernels.  ``pfedsop_update_batched_sharded``
-is the multi-pod layout (DESIGN.md §11): called inside a mesh-engine
+(any float dtype) and returns (x_new, beta); it is
+``pfedsop_update_batched`` at C=1, the same update with a leading
+participating-client axis — (C, N) operands zero-padded to (rows, 128)
+tiles, (C,) betas — backed by the (clients, tiles) grid kernels.
+``pfedsop_update_batched_sharded`` is the multi-pod layout (DESIGN.md
+§11): called inside a mesh-engine
 shard_map body, it sweeps only the local model-axis slice of the tile rows
-and combines the three Gompertz scalars with a cross-shard psum —
-bit-identical to the unsharded batched kernel.  ``pfedsop_update_tree`` is
+and gathers the per-tile partials of the three Gompertz scalars across
+shards — bit-identical to the unsharded batched kernel.  ``pfedsop_update_tree`` is
 the pytree convenience for one client.
 
 Call sites: the production path is ``repro.core.pfedsop.personalize``,
@@ -26,31 +27,28 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.pfedsop_update.kernel import (
-    _split_rows,
-    reduce3_batched_pallas,
-    reduce3_pallas,
+    LANES,
+    reduce3_partials_pallas,
+    sum_partials_pallas,
+    tile_rows,
     update_batched_pallas,
-    update_pallas,
 )
 from repro.kernels.pfedsop_update.ref import gompertz_beta
 from repro.utils.pytree import tree_flatten_to_vector, tree_unflatten_from_vector
 
-LANES = 128
 
-
-def _pad2d(v):
-    n = v.shape[0]
-    m = -(-n // LANES)  # ceil division -> rows
-    pad = m * LANES - n
-    return jnp.pad(v, (0, pad)).reshape(m, LANES), n
-
-
-def _pad3d(v):
-    """(C, N) -> (C, M, 128) lane-aligned tiles (zero padding)."""
-    c, n = v.shape
+def _tiling(n: int):
+    """(rows per tile, tile count) for a flat length-N operand."""
     m = -(-n // LANES)
-    pad = m * LANES - n
-    return jnp.pad(v, ((0, 0), (0, pad))).reshape(c, m, LANES), n
+    rows = tile_rows(m)
+    return rows, -(-m // rows)
+
+
+def _to_tiles(v, rows: int, tiles: int):
+    """(C, N) -> (C, tiles*rows, 128) lane-aligned tiles (zero padding)."""
+    c, n = v.shape
+    return jnp.pad(v, ((0, 0), (0, tiles * rows * LANES - n))).reshape(
+        c, tiles * rows, LANES)
 
 
 def _coeff_from_sums(dot, nl2, ng2, beta, rho):
@@ -62,23 +60,22 @@ def _coeff_from_sums(dot, nl2, ng2, beta, rho):
     return 1.0 / rho - sq / (rho**2 + rho * sq)
 
 
+def _scalars(sums, eta1, rho, lam, eps):
+    """(C, 3) sums -> per-client (beta, eta1 * Sherman-Morrison coeff)."""
+    dot, nl2, ng2 = sums[:, 0], sums[:, 1], sums[:, 2]
+    beta = gompertz_beta(dot, nl2, ng2, lam, eps)  # elementwise -> (C,)
+    return beta, eta1 * _coeff_from_sums(dot, nl2, ng2, beta, rho)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pfedsop_update(x, delta_i, delta_g, eta1=0.01, rho=1.0, lam=1.0,
                    eps=1e-12, interpret: bool = False):
-    """Flat-vector fused update.  Returns (x_new (N,), beta scalar f32)."""
-    di2d, n = _pad2d(delta_i)
-    dg2d, _ = _pad2d(delta_g)
-    x2d, _ = _pad2d(x)
-
-    partials = reduce3_pallas(di2d, dg2d, interpret=interpret)  # (tiles, 3)
-    sums = jnp.sum(partials, axis=0)
-    dot, nl2, ng2 = sums[0], sums[1], sums[2]
-
-    beta = gompertz_beta(dot, nl2, ng2, lam, eps)
-    coeff = _coeff_from_sums(dot, nl2, ng2, beta, rho)
-
-    out2d = update_pallas(x2d, di2d, dg2d, beta, eta1 * coeff, interpret=interpret)
-    return out2d.reshape(-1)[:n], beta
+    """Flat-vector fused update for one client: the batched kernel at C=1.
+    Returns (x_new (N,), beta scalar f32)."""
+    out, beta = pfedsop_update_batched(x[None], delta_i[None], delta_g,
+                                       eta1=eta1, rho=rho, lam=lam, eps=eps,
+                                       interpret=interpret)
+    return out[0], beta[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -93,20 +90,16 @@ def pfedsop_update_batched(x, delta_i, delta_g, eta1=0.01, rho=1.0, lam=1.0,
     """
     if delta_g.ndim == 1:
         delta_g = delta_g[None]
-    di3d, n = _pad3d(delta_i)
-    dg3d, _ = _pad3d(delta_g)
-    x3d, _ = _pad3d(x)
+    c, n = x.shape
+    rows, tiles = _tiling(n)
+    di3d, dg3d, x3d = (_to_tiles(v, rows, tiles) for v in (delta_i, delta_g, x))
 
-    partials = reduce3_batched_pallas(di3d, dg3d, interpret=interpret)
-    sums = jnp.sum(partials, axis=1)  # (C, 3)
-    dot, nl2, ng2 = sums[:, 0], sums[:, 1], sums[:, 2]
-
-    beta = gompertz_beta(dot, nl2, ng2, lam, eps)  # elementwise -> (C,)
-    coeff = _coeff_from_sums(dot, nl2, ng2, beta, rho)
-
-    out3d = update_batched_pallas(x3d, di3d, dg3d, beta, eta1 * coeff,
+    partials = reduce3_partials_pallas(di3d, dg3d, rows, interpret=interpret)
+    beta, ec = _scalars(sum_partials_pallas(partials, interpret=interpret),
+                        eta1, rho, lam, eps)
+    out3d = update_batched_pallas(x3d, di3d, dg3d, beta, ec, rows,
                                   interpret=interpret)
-    return out3d.reshape(x.shape[0], -1)[:, :n], beta
+    return out3d.reshape(c, -1)[:, :n], beta
 
 
 def pfedsop_update_batched_sharded(x, delta_i, delta_g, axis_name: str,
@@ -117,71 +110,55 @@ def pfedsop_update_batched_sharded(x, delta_i, delta_g, axis_name: str,
     Runs INSIDE a shard_map body whose mesh carries a model-role axis
     ``axis_name`` of size ``n_shards`` (DESIGN.md §11); operands are the
     same replicated (C, N) buffers as ``pfedsop_update_batched``.  Each
-    shard sweeps only its contiguous run of tile rows:
+    shard sweeps only its contiguous run of tiles:
 
       1. slice   — tiles are assigned to shards at the UNSHARDED kernel's
-                   tile granularity (``_split_rows(M, 512)`` rows per
-                   tile), zero-padding the tile count up to a multiple of
-                   ``n_shards``; shard s takes tiles [s*Tl, (s+1)*Tl).
+                   tile granularity (``_tiling(N)``), zero-padding the
+                   tile count up to a multiple of ``n_shards``; shard s
+                   takes tiles [s*Tl, (s+1)*Tl).
       2. reduce  — the (clients, local tiles) grid kernel emits per-tile
                    partials for the three Gompertz scalars (<d_i,d_g>,
-                   ||d_i||^2, ||d_g||^2 — Eqs. 10-13); each shard scatters
-                   them into its tile range of a zero (C, T, 3) buffer and
-                   a cross-shard **psum** over ``axis_name`` reconstructs
-                   the full per-tile partial array exactly (disjoint
-                   supports: x + 0.0 is exact).
+                   ||d_i||^2, ||d_g||^2 — Eqs. 10-13); an all_gather over
+                   ``axis_name`` (tiled, shard order) reconstructs the
+                   full per-tile partial array exactly, and the same
+                   tile-order sum kernel as the unsharded path reduces it.
       3. scalars — beta (Gompertz, Eq. 14) and the Sherman-Morrison
-                   coefficient from the summed partials, identically on
-                   every shard (replicated scalars).
+                   coefficient from the sums, identically on every shard
+                   (replicated scalars).
       4. update  — each shard updates its own tile slice and an all_gather
                    over ``axis_name`` reassembles (C, N).
 
     Bitwise contract: because the tile decomposition, the per-tile partial
-    values and the tile-axis summation order all match the unsharded
-    batched kernel, the result is bit-identical to
-    ``pfedsop_update_batched`` on the same operands — the anchor of the
-    §11 degenerate-parity guarantee (vmap == 1-D shard_map == multi-pod,
-    tests/test_multipod.py).  A psum of per-SHARD sums would be cheaper by
-    a few bytes but would re-associate the float reduction and break that
-    contract.
+    values and the tile-order summation all match the unsharded batched
+    kernel, the result is bit-identical to ``pfedsop_update_batched`` on
+    the same operands — the anchor of the §11 degenerate-parity guarantee
+    (vmap == 1-D shard_map == multi-pod, tests/test_multipod.py).  A psum
+    of per-SHARD sums would be cheaper by a few bytes but would
+    re-associate the float reduction and break that contract.
     """
     lax = jax.lax
 
     if delta_g.ndim == 1:
         delta_g = delta_g[None]
-    di3d, n = _pad3d(delta_i)
-    dg3d, _ = _pad3d(delta_g)
-    x3d, _ = _pad3d(x)
-    c, m, _lanes = x3d.shape
-
-    # tile layout of the UNSHARDED kernel (the bitwise reference); the
-    # shared (1, M, 128) broadcast delta slices the same way per shard
-    rows = _split_rows(m, 512)
-    t = m // rows  # total tiles
-    t_loc = -(-t // n_shards)  # tiles per shard (ceil)
-    m_pad = t_loc * n_shards * rows
-    padrows = lambda a: jnp.pad(a, ((0, 0), (0, m_pad - m), (0, 0)))
+    c, n = x.shape
+    rows, tiles = _tiling(n)  # the UNSHARDED kernel's tiles (bitwise ref)
+    t_loc = -(-tiles // n_shards)  # tiles per shard (ceil)
+    di3d, dg3d, x3d = (_to_tiles(v, rows, t_loc * n_shards)
+                       for v in (delta_i, delta_g, x))
     idx = lax.axis_index(axis_name)
-    sl = lambda a: lax.dynamic_slice_in_dim(padrows(a), idx * t_loc * rows,
+    sl = lambda a: lax.dynamic_slice_in_dim(a, idx * t_loc * rows,
                                             t_loc * rows, axis=1)
     di_l, dg_l, x_l = sl(di3d), sl(dg3d), sl(x3d)
 
-    # per-tile partials on the local tiles, at the reference tile size
-    part_l = reduce3_batched_pallas(di_l, dg_l, block_rows=rows,
-                                    interpret=interpret)  # (C, t_loc, 3)
-    full = jnp.zeros((c, t_loc * n_shards, 3), jnp.float32)
-    full = lax.dynamic_update_slice_in_dim(full, part_l, idx * t_loc, axis=1)
-    partials = lax.psum(full, axis_name)[:, :t, :]  # exact reconstruction
-    sums = jnp.sum(partials, axis=1)  # (C, 3) — same order as unsharded
-    dot, nl2, ng2 = sums[:, 0], sums[:, 1], sums[:, 2]
+    part_l = reduce3_partials_pallas(di_l, dg_l, rows, interpret=interpret)
+    partials = lax.all_gather(part_l, axis_name, axis=1, tiled=True)[:, :tiles]
+    beta, ec = _scalars(sum_partials_pallas(partials, interpret=interpret),
+                        eta1, rho, lam, eps)  # (C,) — replicated
 
-    beta = gompertz_beta(dot, nl2, ng2, lam, eps)  # (C,) — replicated
-    coeff = _coeff_from_sums(dot, nl2, ng2, beta, rho)
-
-    out_l = update_batched_pallas(x_l, di_l, dg_l, beta, eta1 * coeff,
-                                  block_rows=rows, interpret=interpret)
-    out = lax.all_gather(out_l, axis_name, axis=1, tiled=True)  # (C, m_pad, 128)
-    return out[:, :m, :].reshape(x.shape[0], -1)[:, :n], beta
+    out_l = update_batched_pallas(x_l, di_l, dg_l, beta, ec, rows,
+                                  interpret=interpret)
+    out = lax.all_gather(out_l, axis_name, axis=1, tiled=True)
+    return out.reshape(c, -1)[:, :n], beta
 
 
 def pfedsop_update_tree(params, delta_i, delta_g, eta1=0.01, rho=1.0, lam=1.0,

@@ -27,14 +27,19 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 def rmsnorm_pallas(x, scale, eps: float = 1e-6, block_rows: int = 256,
                    interpret: bool = False):
-    """x: (N, D) (callers flatten leading dims); scale: (D,)."""
+    """x: (N, D) (callers flatten leading dims); scale: (D,).
+
+    Row blocks are ``block_rows`` (a multiple of 8), or N rounded up to a
+    multiple of 8 when N is smaller; N is zero-padded to whole blocks (a
+    zero row normalizes to zero) and the padding sliced off the output.
+    """
     n, d = x.shape
-    rows = min(block_rows, n)
-    while n % rows:
-        rows //= 2
-    rows = max(rows, 1)
-    grid = (n // rows,)
-    return pl.pallas_call(
+    rows = min(block_rows, -(-n // 8) * 8)
+    n_pad = -(-n // rows) * rows
+    if n_pad != n:
+        x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
+    grid = (n_pad // rows,)
+    out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=grid,
         in_specs=[
@@ -42,6 +47,7 @@ def rmsnorm_pallas(x, scale, eps: float = 1e-6, block_rows: int = 256,
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), x.dtype),
         interpret=interpret,
     )(x, scale)
+    return out if n_pad == n else out[:n]

@@ -1,5 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 placeholder CPU devices, on the CPU even where a TPU is attached (the
+# chip stays free for the process that owns it); the caller's flags are kept
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512"]))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Calibrated roofline: exact per-layer unit costs from the compiled
 artifact, composed analytically over the loop trip counts.

@@ -1,5 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 placeholder CPU devices, on the CPU even where a TPU is attached (the
+# chip stays free for the process that owns it); the caller's flags are kept
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512"]))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) combination against the production mesh, with NO device allocation
@@ -15,9 +19,10 @@ Per combination it records to experiments/dryrun/<arch>__<shape>__<mesh>.json:
   - the collective-op byte census parsed from the post-SPMD HLO text
   - input/output sharding specs (audit trail)
 
-The XLA_FLAGS line above MUST run before any jax import: jax locks the
-device count at first initialisation.  Do not set it globally - smoke
-tests and benches see the real single CPU device.
+The environment lines above MUST run before any jax import: jax reads
+the platform at import and locks the device count at first
+initialisation.  Do not set them globally - smoke tests and benches see
+the real single CPU device.
 """
 import argparse
 import json

@@ -203,7 +203,12 @@ def resolve_mesh(spec: MeshSpec):
             f"{n} for CPU simulation (see dryrun.py), or pick a smaller "
             f"spec ({_MESH_GRAMMAR})"
         )
-    return jax.make_mesh(spec.shape, spec.axes, devices=devices[:n])
+    # Auto axes: the engines place data with ``with_sharding_constraint``
+    # and shard_map specs, not with sharding-in-types (jax's default for
+    # ``make_mesh`` is Explicit axes, which rejects that style)
+    auto = (jax.sharding.AxisType.Auto,) * len(spec.axes)
+    return jax.make_mesh(spec.shape, spec.axes, axis_types=auto,
+                         devices=devices[:n])
 
 
 # ---------------------------------------------------------------------------

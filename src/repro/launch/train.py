@@ -29,6 +29,7 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer as tf
 from repro.obs import ObsConfig, make_obs
 from repro.utils.checkpoint import save_checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -69,6 +70,7 @@ def main():
     ap.add_argument("--obs-quiet", action="store_true",
                     help="suppress stdout progress lines (records still trace)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.xla_profile >= 0 and not args.trace_dir:
         ap.error("--xla-profile requires --trace-dir")
 

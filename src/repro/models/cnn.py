@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.optim.reduce import ordered_axis_sum
+
 
 def _conv_init(key, kh, kw, cin, cout):
     fan_in = kh * kw * cin
@@ -107,7 +109,10 @@ def loss_fn(params, cfg, batch):
     logits = apply(params, cfg, batch["images"]).astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, batch["labels"][:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - gold)
+    # halving-tree batch mean: ``jnp.mean`` lets XLA pick the association,
+    # and its pick follows the vmapped client count, so a client's loss
+    # would depend on how many clients share its device (DESIGN.md §11)
+    return ordered_axis_sum(logz - gold) / logz.shape[0]
 
 
 def accuracy(params, cfg, batch):

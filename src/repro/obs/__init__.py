@@ -212,13 +212,11 @@ class Obs:
                 or self.cfg.xla_profile < 0 or t != self.cfg.xla_profile):
             return
         import jax
-        try:
-            jax.profiler.start_trace(str(self.tracer.dir / "xla"))
-            self._xla_active = True
-            self.event("xla_profile_start", round=t)
-        except Exception as e:  # profiler backend may be absent on CPU
-            self.log.debug(f"xla profiler unavailable: {e}",
-                           event="xla_profile_error")
+        # a requested capture that cannot start fails the run: a run that
+        # exits 0 without the profile it was asked for hides the fault
+        jax.profiler.start_trace(str(self.tracer.dir / "xla"))
+        self._xla_active = True
+        self.event("xla_profile_start", round=t)
 
     def xla_round_end(self, t: int) -> None:
         if not self._xla_active:

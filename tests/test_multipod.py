@@ -27,10 +27,7 @@ _MULTIPOD_SCRIPT = textwrap.dedent(
     import jax, numpy as np, jax.numpy as jnp
     assert len(jax.devices()) == 8, jax.devices()
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.configs.resnet_cifar import SMALL_CNN as CFG
     from repro.core.baselines import METHODS
@@ -46,7 +43,9 @@ _MULTIPOD_SCRIPT = textwrap.dedent(
     # -- 1. model-sharded kernel op: bitwise vs the unsharded kernel ------
     mesh = resolve_mesh(MeshSpec.multi_pod(2, 2, 2))
     k = jax.random.PRNGKey(0)
-    for n in [130, 4096 + 7]:  # sub-tile and non-tile-multiple N
+    # sub-tile N, a non-tile-multiple N, and an N of five 512-row tiles,
+    # so that shard 1 holds real tiles (3, 4) as well as padding
+    for n in [130, 4096 + 7, 2 * 2 * 512 * 128 + 7]:
         x, di = (jax.random.normal(jax.random.fold_in(k, i), (4, n))
                  for i in (1, 2))
         dg = jax.random.normal(jax.random.fold_in(k, 3), (n,))
@@ -55,7 +54,7 @@ _MULTIPOD_SCRIPT = textwrap.dedent(
             lambda x, di, dg: pfedsop_update_batched_sharded(
                 x, di, dg, "model", 2, interpret=True),
             mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
-            check_rep=False)(x, di, dg)
+            check_vma=False)(x, di, dg)
         assert np.array_equal(np.asarray(ref), np.asarray(out)), n
         assert np.array_equal(np.asarray(beta_ref), np.asarray(beta)), n
     print("KERNEL_SHARDED_BITWISE_OK")

@@ -251,6 +251,22 @@ class TestObsFacade:
         evs = read_events(tmp_path / "t")
         assert [e["name"] for e in evs] == ["marker"]
 
+    def test_failed_xla_profile_start_raises(self, tmp_path, monkeypatch):
+        """A requested --xla-profile capture that cannot start must fail
+        the run, not leave one that exits 0 without the profile."""
+
+        def refuse(*_a, **_k):
+            raise RuntimeError("profiler unavailable")
+
+        monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+        obs = Obs(ObsConfig(trace_dir=str(tmp_path / "t"), level="phase",
+                            xla_profile=1))
+        obs.open()
+        obs.xla_round_start(0)  # not the requested round: no capture
+        with pytest.raises(RuntimeError, match="profiler unavailable"):
+            obs.xla_round_start(1)
+        obs.close()
+
     def test_default_metrics_path_lands_in_trace_dir(self, tmp_path):
         obs = Obs(ObsConfig(trace_dir=str(tmp_path / "t"), level="phase"))
         obs.open()
