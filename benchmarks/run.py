@@ -1074,7 +1074,7 @@ def main():
                     help="trace supporting benches (multipod-engine) into "
                          "per-run subdirs here (DESIGN.md §13); summarize "
                          "with scripts/trace_report.py")
-    ap.add_argument("--obs-level", choices=["round", "phase", "kernel"],
+    ap.add_argument("--obs-level", choices=["round", "phase"],
                     default="phase",
                     help="instrumentation depth for --trace-dir runs")
     args = ap.parse_args()

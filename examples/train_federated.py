@@ -212,12 +212,12 @@ def main():
                     help="metrics.jsonl path ('' = <trace-dir>/<method>/"
                          "metrics.jsonl when tracing); counters/gauges/"
                          "histograms snapshot once per applied server update")
-    ap.add_argument("--obs-level", choices=["off", "round", "phase", "kernel"],
+    ap.add_argument("--obs-level", choices=["off", "round", "phase"],
                     default="phase",
                     help="instrumentation depth (DESIGN.md §13): round = "
-                         "round spans + metrics; phase = + per-phase spans "
-                         "with block-until-ready boundaries; kernel = + "
-                         "jax.profiler annotations around kernel launches")
+                         "round spans + metrics + non-blocking sample/"
+                         "dispatch/sync spans; phase = per-phase spans with "
+                         "block-until-ready boundaries instead of dispatch")
     ap.add_argument("--xla-profile", type=int, default=-1,
                     help="capture a jax.profiler trace of this round/version "
                          "index under <trace-dir>/<method>/xla (-1 = off; "

@@ -7,7 +7,11 @@ exists to answer:
 - **phase breakdown** — wall-clock per server phase (gather / client /
   all_gather / eval / aggregate / scatter, plus the async dispatch
   pipeline), warm means with the compile round excluded, as a share of
-  round time.  Pointed at several runs at once (e.g. the per-backend
+  round time.  A ``round``-level trace (non-blocking) splits the round's
+  host time instead: ``sample`` (the host's draws and gathers), one
+  ``dispatch.<phase>`` per program call, and ``sync`` (the wait for the
+  device's results); a ``phase``-level trace holds ``sample`` and
+  ``sync`` beside its blocking phase spans.  Pointed at several runs at once (e.g. the per-backend
   subdirs ``benchmarks/run.py --only multipod-engine --trace-dir ...``
   leaves behind) it prints a side-by-side comparison — the
   shard_map-vs-mesh gap decomposes into per-phase deltas, with the
@@ -39,8 +43,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 from repro.obs import read_events, read_metrics  # noqa: E402
 
 # server phases in pipeline order; anything else recorded lands after
-PHASE_ORDER = ["gather", "client", "all_gather", "eval", "aggregate",
-               "aggregate_stale", "scatter", "train_step", "round"]
+_PIPELINE = ["gather", "client", "all_gather", "eval", "aggregate",
+             "aggregate_stale", "scatter"]
+PHASE_ORDER = (["sample"] + _PIPELINE + [f"dispatch.{p}" for p in _PIPELINE]
+               + ["sync", "train_step", "round"])
 
 
 def discover(paths):

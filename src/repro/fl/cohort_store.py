@@ -290,14 +290,17 @@ class DeviceStore(CohortStore):
             lambda x: jnp.broadcast_to(jnp.asarray(x), (k,) + jnp.shape(x)),
             proto,
         )
-        self._gather = jax.jit(
-            lambda full, ids: jax.tree.map(lambda x: x[ids], full)
-        )
-        self._scatter = jax.jit(
-            lambda full, ids, new: jax.tree.map(
-                lambda f, n: f.at[ids].set(n), full, new
-            )
-        )
+
+        # named functions: the XLA modules jit_store_gather /
+        # jit_store_scatter, which a device trace attributes to the store
+        def store_gather(full, ids):
+            return jax.tree.map(lambda x: x[ids], full)
+
+        def store_scatter(full, ids, new):
+            return jax.tree.map(lambda f, n: f.at[ids].set(n), full, new)
+
+        self._gather = jax.jit(store_gather)
+        self._scatter = jax.jit(store_scatter)
 
     def gather(self, ids, shardings=None):
         # shardings are an h2d placement hint; the resident stack already

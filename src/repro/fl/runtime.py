@@ -243,14 +243,17 @@ class RoundPrograms:
         self.obs = NOOP
         method_ = method
 
-        def _aggregate(broadcast, uploads):
+        # every program's function name is its XLA module's name
+        # (``jit_<name>``), which is how a device trace attributes its
+        # operations to a phase (DESIGN.md §13)
+        def aggregate(broadcast, uploads):
             return method_.server_update(broadcast, uploads)
 
-        def _aggregate_stale(broadcast, uploads, staleness):
+        def aggregate_stale(broadcast, uploads, staleness):
             return method_.server_update_stale(broadcast, uploads, staleness)
 
-        self.aggregate = jax.jit(_aggregate)
-        self.aggregate_stale = jax.jit(_aggregate_stale)
+        self.aggregate = jax.jit(aggregate)
+        self.aggregate_stale = jax.jit(aggregate_stale)
 
     def seen_cohorts(self):
         """Cohort sizes an engine was actually instantiated for (sorted)."""
@@ -293,11 +296,11 @@ class RoundPrograms:
             def one_client(state, broadcast, batch_seq):
                 return method.client_round(loss_fn, state, broadcast, batch_seq)
 
-            def run(gathered_states, broadcast, batches):
+            def client_round(gathered_states, broadcast, batches):
                 return engine.client_phase_sharded(one_client, gathered_states,
                                                    broadcast, batches)
 
-            fn = jax.jit(run)
+            fn = jax.jit(client_round)
             if self.grad_chunks > 1:
                 # jit defers tracing to the first call, so the run-level
                 # chunk count is announced around every call — the traced
@@ -340,11 +343,11 @@ class RoundPrograms:
             engine = self.engine(cohort)
             method_ = self.method
 
-            def run(broadcast, uploads):
+            def aggregate_sharded(broadcast, uploads):
                 return engine.aggregate_phase(
                     method_.server_update, broadcast, uploads)
 
-            fn = jax.jit(run)
+            fn = jax.jit(aggregate_sharded)
             self._aggregate_sharded[key] = fn
             self.obs.event("program_cache_miss", cat="compile",
                            program="aggregate_sharded", cohort=cohort,
@@ -362,6 +365,7 @@ class RoundPrograms:
         key = self._key(cohort)
         fn = self._replicate.get(key, False)
         if fn is False:
+            # the engine method's own name gives module jit_replicate
             rep = getattr(self.engine(cohort), "replicate", None)
             fn = None if rep is None else jax.jit(rep)
             self._replicate[key] = fn
@@ -392,10 +396,10 @@ class RoundPrograms:
                 params = method.eval_params(state, broadcast)
                 return acc_fn(params, test)
 
-            def run(states, broadcast, test_sets):
+            def eval_round(states, broadcast, test_sets):
                 return engine.eval_phase(one_eval, states, broadcast, test_sets)
 
-            fn = jax.jit(run)
+            fn = jax.jit(eval_round)
             self._eval[key] = fn
             self.obs.event("program_cache_miss", cat="compile",
                            program="eval", cohort=cohort, signature=key[1])
@@ -540,6 +544,16 @@ class Federation:
             # (capacity threshold, §12) — surface it on the timeline
             self.obs.event("mmap_promote", store=self.store.describe())
 
+    def attach_obs(self, obs):
+        """Switch a live federation's observability to the facade ``obs``
+        (an ``Obs``; ``repro.obs.NOOP`` switches it off) and open it
+        under this run's fingerprint.  The driver and its round programs
+        report to it from the next round on; programs and values are
+        unchanged (tests/test_obs_invariance.py).  Closing it is the
+        caller's.  Returns ``obs``."""
+        self.obs = self.programs.obs = obs
+        return obs.open(self._obs_fingerprint())
+
     def _observe_client_metrics(self, metrics) -> None:
         """Per-client method diagnostics -> histograms: the Gompertz
         weight beta and its angle theta (recovered host-side from Eq. 14's
@@ -576,10 +590,21 @@ class Federation:
     # -- round loop -------------------------------------------------------
 
     def run_round(self):
+        """One round.  Returns the cohort's mean ``loss`` and ``acc``, its
+        client ids (``clients``) and the real (unpadded) test rows its
+        eval covered (``eval_samples``).
+
+        Traced at ``round`` level the round records ``sample`` (the host's
+        draws and gathers), one ``dispatch.<phase>`` span per program call
+        and ``sync`` (the wait for the accuracies, where the round meets
+        the device), and never blocks elsewhere; at ``phase`` level the
+        program calls block under their phase names (``Obs.timed``)."""
         obs = self.obs
-        ids = self.rng.choice(self.cfg.n_clients, self.kprime, replace=False)
-        batches = self.data.sample_round_batches(self.rng, ids, self.T, self.cfg.batch)
-        tests = self.data.client_test_set(ids)
+        with obs.span("sample"):
+            ids = self.rng.choice(self.cfg.n_clients, self.kprime, replace=False)
+            batches = self.data.sample_round_batches(self.rng, ids, self.T,
+                                                     self.cfg.batch)
+            tests = self.data.client_test_set(ids)
         gathered = obs.timed(
             "gather", self.store.gather,
             ids, self.programs.gather_shardings(self.kprime, self._store_struct)
@@ -606,7 +631,8 @@ class Federation:
         # measures submit time only.
         obs.timed("scatter", self.store.scatter, ids, new_states, sync=False)
 
-        accs = np.asarray(accs, np.float64)
+        with obs.span("sync"):
+            accs = np.asarray(accs, np.float64)
         self.best_acc[ids] = np.maximum(self.best_acc[ids], accs)
         self.participated[ids] = True
         if self.availability is not None:
@@ -617,6 +643,8 @@ class Federation:
         return {
             "loss": float(np.mean(np.asarray(metrics["loss"]))),
             "acc": float(np.mean(accs)),
+            "clients": ids,
+            "eval_samples": int(self.data.test_counts[ids].sum()),
         }
 
     def run(self, verbose: bool = False):

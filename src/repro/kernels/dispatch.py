@@ -192,21 +192,13 @@ def current_grad_chunks() -> int:
 
 @contextlib.contextmanager
 def kernel_scope(kernel: str, impl: str):
-    """Name a dispatched-kernel launch in profiles (DESIGN.md §13).
+    """Name a dispatched-kernel launch in HLO (DESIGN.md §13).
 
-    Always wraps tracing in ``jax.named_scope`` so the resolved impl shows
-    up in HLO op names / XLA profiles for free; at ``kernel`` obs level it
-    additionally opens a ``jax.profiler.TraceAnnotation`` so the launch is
-    attributable in a ``--xla-profile`` capture.  Host-side only — the
+    Wraps tracing in ``jax.named_scope("{kernel}[{impl}]")`` so the
+    resolved impl shows up in the HLO op metadata.  Host-side only — the
     traced computation is unchanged (names, not values).
     """
-    from repro.obs import LEVEL_KERNEL, get_obs
-
-    label = f"{kernel}[{impl}]"
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.named_scope(label))
-        if get_obs().level >= LEVEL_KERNEL:
-            stack.enter_context(jax.profiler.TraceAnnotation(label))
+    with jax.named_scope(f"{kernel}[{impl}]"):
         yield
 
 

@@ -62,7 +62,7 @@ def main():
                          "(DESIGN.md §13)")
     ap.add_argument("--metrics", default="",
                     help="metrics.jsonl path ('' = <trace-dir>/metrics.jsonl)")
-    ap.add_argument("--obs-level", choices=["off", "round", "phase", "kernel"],
+    ap.add_argument("--obs-level", choices=["off", "round", "phase"],
                     default="phase")
     ap.add_argument("--xla-profile", type=int, default=-1,
                     help="round index to wrap in a jax.profiler capture "

@@ -12,15 +12,21 @@ Three instruments behind one ``Obs`` facade:
 
 The hard contract (tests/test_obs_invariance.py): observability NEVER
 touches traced values.  Every instrument reads host-side numbers the run
-already produced; the only on-path effect of enabling it is wall-clock
-(``timed`` blocks between phases so span durations are honest).  With it
-off (``FLRunConfig.obs = None``, the default) the drivers hold the
-shared ``NOOP`` facade: no files, no objects, no extra synchronization —
-training histories are bitwise identical to an uninstrumented build.
+already produced; the only on-path effect of enabling it at ``phase``
+level is wall-clock (``timed`` blocks between phases so span durations
+are honest).  With it off (``FLRunConfig.obs = None``, the default) the
+drivers hold the shared ``NOOP`` facade: no files, no objects, no extra
+synchronization — training histories are bitwise identical to an
+uninstrumented build.
 
-Levels: ``off`` < ``round`` (round spans + metrics) < ``phase``
-(+ per-phase spans with block-until-ready boundaries) < ``kernel``
-(+ ``jax.profiler`` annotations around kernel launches, §9).
+Levels: ``off`` < ``round`` (round spans, metrics, and non-blocking
+spans inside the round: ``sample``, ``dispatch.<phase>`` — the host time
+of each program call — and ``sync``; no ``block_until_ready``, so the
+device pipeline runs as it does untraced) < ``phase`` (per-phase spans
+under the phase's own name, blocking between phases, plus ``sample`` and
+``sync``).  Span ``ts`` is epoch microseconds, so a ``jax.profiler``
+trace (whose events count from its ``profile_start_time``, epoch ns)
+lines up with one shift.
 """
 from __future__ import annotations
 
@@ -38,11 +44,11 @@ __all__ = [
     "OBS_LEVELS", "ObsConfig", "Obs", "NOOP", "make_obs", "as_obs_config",
     "get_obs", "ObsLog", "MetricsRegistry", "Histogram", "Tracer",
     "export_chrome", "read_events", "read_metrics",
-    "LEVEL_OFF", "LEVEL_ROUND", "LEVEL_PHASE", "LEVEL_KERNEL",
+    "LEVEL_OFF", "LEVEL_ROUND", "LEVEL_PHASE",
 ]
 
-OBS_LEVELS = ("off", "round", "phase", "kernel")
-LEVEL_OFF, LEVEL_ROUND, LEVEL_PHASE, LEVEL_KERNEL = range(4)
+OBS_LEVELS = ("off", "round", "phase")
+LEVEL_OFF, LEVEL_ROUND, LEVEL_PHASE = range(3)
 
 
 @dataclass(frozen=True)
@@ -165,21 +171,25 @@ class Obs:
         return self.tracer.span(name, **kw)
 
     def timed(self, name: str, fn, *args, sync: bool = True, **meta):
-        """Run ``fn(*args)`` under a phase span (level ``phase``+).
+        """Run ``fn(*args)`` under a span of its host or device time.
 
-        ``sync`` blocks on the outputs so the span measures the phase's
-        actual device time, not its dispatch time — the documented
-        wall-clock-only cost of enabling phase tracing.  ``sync=False``
-        is for phases whose deferral IS the design (the store's
-        overlapped d2h scatter).  Below phase level this is exactly
-        ``fn(*args)``.
+        At ``phase`` level the span is ``name`` and ``sync`` blocks on
+        the outputs, so it measures the phase's actual device time, not
+        its dispatch time — the documented wall-clock-only cost of phase
+        tracing.  ``sync=False`` is for phases whose deferral IS the
+        design (the store's overlapped d2h scatter).  At ``round`` level
+        the span is ``dispatch.<name>``, the host time of the call alone
+        (argument transfers and the launch), and nothing blocks.  Below
+        that this is exactly ``fn(*args)``.
         """
-        if self.tracer is None or self.level < LEVEL_PHASE:
+        if self.tracer is None or self.level < LEVEL_ROUND:
             return fn(*args)
         ts = time.time_ns() // 1000
         t0 = time.perf_counter_ns()
         out = fn(*args)
-        if sync:
+        if self.level < LEVEL_PHASE:
+            name = "dispatch." + name
+        elif sync:
             import jax
             out = jax.block_until_ready(out)
         self.tracer.complete(name, ts, (time.perf_counter_ns() - t0) // 1000,

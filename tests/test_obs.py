@@ -242,14 +242,22 @@ class TestObsFacade:
         assert spans[0]["name"] == "work"
         assert spans[0]["args"]["round"] == 0
 
-    def test_round_level_skips_phase_spans(self, tmp_path):
+    def test_round_level_skips_phase_spans(self, tmp_path, monkeypatch):
+        """At ``round`` level a timed call records its host time as
+        ``dispatch.<name>`` and never blocks on its outputs; no span goes
+        under the phase's own name."""
+        waits = []
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: waits.append(x) or x)
         obs = Obs(ObsConfig(trace_dir=str(tmp_path / "t"), level="round"))
         obs.open()
-        obs.timed("work", lambda: 1)
+        assert obs.timed("work", lambda: 1, round=3) == 1
         obs.event("marker")
         obs.close()
         evs = read_events(tmp_path / "t")
-        assert [e["name"] for e in evs] == ["marker"]
+        assert [e["name"] for e in evs] == ["dispatch.work", "marker"]
+        assert evs[0]["args"] == {"round": 3} and evs[0]["dur"] >= 0
+        assert waits == []
 
     def test_failed_xla_profile_start_raises(self, tmp_path, monkeypatch):
         """A requested --xla-profile capture that cannot start must fail
@@ -345,6 +353,72 @@ class TestFederationObs:
         # quiet mode: round prints were recorded, not printed
         logs = [e for e in evs if e.get("k") == "log" and e["event"] == "round"]
         assert len(logs) == 2
+
+    @staticmethod
+    def _round_spans(evs):
+        """Span names of each traced round, in the order they closed (the
+        driver's own ``round`` span closes last)."""
+        rounds, cur = [], []
+        for e in evs:
+            if e.get("k") == "span":
+                cur.append(e["name"])
+                if e["name"] == "round":
+                    rounds.append(cur)
+                    cur = []
+        return rounds
+
+    def test_round_level_spans_in_order_without_blocking(self, tmp_path,
+                                                         monkeypatch):
+        """Level ``round``: each round records sample, one dispatch span
+        per program call and sync, and never calls block_until_ready."""
+        waits = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: waits.append(1) or real(x))
+        tdir = tmp_path / "t"
+        fed = self._fed(tmp_path, obs=ObsConfig(trace_dir=str(tdir),
+                                                level="round", quiet=True))
+        fed.run()
+        assert waits == []
+        want = ["sample", "dispatch.gather", "dispatch.client", "dispatch.eval",
+                "dispatch.aggregate", "dispatch.scatter", "sync", "round"]
+        assert self._round_spans(read_events(tdir)) == [want, want]
+
+    def test_phase_level_keeps_phase_names_and_adds_sample_sync(self, tmp_path):
+        tdir = tmp_path / "t"
+        self._fed(tmp_path, obs=ObsConfig(trace_dir=str(tdir), level="phase",
+                                          quiet=True)).run()
+        want = ["sample", "gather", "client", "eval", "aggregate", "scatter",
+                "sync", "round"]
+        assert self._round_spans(read_events(tdir)) == [want, want]
+
+    def test_round_spans_share_the_epoch_clock(self, tmp_path):
+        """Span ``ts`` is epoch microseconds: a device trace's events,
+        counted from its epoch-ns ``profile_start_time``, line up with
+        one shift."""
+        import time
+
+        t0 = time.time_ns() // 1000
+        tdir = tmp_path / "t"
+        self._fed(tmp_path, obs=ObsConfig(trace_dir=str(tdir), level="round",
+                                          quiet=True)).run()
+        t1 = time.time_ns() // 1000
+        spans = [e for e in read_events(tdir) if e.get("k") == "span"]
+        assert all(t0 <= e["ts"] and e["ts"] + e["dur"] <= t1 for e in spans)
+        # the round's inner spans follow one another (1 us: the two
+        # clocks' roundings)
+        inner = [e for e in spans if e["name"] != "round"]
+        assert all(a["ts"] + a["dur"] <= b["ts"] + 1
+                   for a, b in zip(inner, inner[1:]))
+
+    def test_run_round_reports_its_cohort(self, tmp_path):
+        fed = self._fed(tmp_path)
+        for _ in range(3):
+            m = fed.run_round()
+            ids = np.asarray(m["clients"])
+            assert len(set(ids.tolist())) == len(ids) == fed.kprime
+            assert m["eval_samples"] == int(fed.data.test_counts[ids].sum()) > 0
+            assert fed.participated[ids].all()
 
     def test_same_config_reopen_appends(self, tmp_path):
         obs = ObsConfig(trace_dir=str(tmp_path / "t"), level="round",

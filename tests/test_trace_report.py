@@ -72,6 +72,23 @@ class TestMissingPhaseRendering:
         assert "client" in out and "—" in out
 
 
+class TestRoundLevelSpans:
+    def test_round_level_spans_render_in_pipeline_order(self, tmp_path, capsys):
+        """A non-blocking (level ``round``) trace: sample, the program
+        calls' dispatch spans in pipeline order, then sync."""
+        run = _write_run(tmp_path, "round_level", {
+            "sync": [400, 300], "dispatch.scatter": [5, 5],
+            "dispatch.client": [50, 40], "sample": [30, 20],
+            "dispatch.gather": [10, 8], "round": [600, 500]})
+        rep = trace_report.report_run(run, top_k=3)
+        trace_report.print_run(rep)
+        rows = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+                if line.strip().split(" ")[0] in rep["phases"]]
+        assert rows == ["sample", "dispatch.gather", "dispatch.client",
+                        "dispatch.scatter", "sync", "round"]
+        assert rep["phases"]["sync"]["warm_mean_us"] == 300
+
+
 class TestHistogramRendering:
     def test_unobserved_histogram_renders(self):
         # Histogram.snapshot() of a never-observed histogram: min/max None
