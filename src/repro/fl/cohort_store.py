@@ -14,8 +14,9 @@ K becomes a throughput knob instead of a memory limit.  Three stores
 behind one interface, selected by ``StoreConfig.kind``:
 
   DeviceStore  the seed behaviour: stacked jnp tree resident on device,
-               gather/scatter are the jitted take/at[ids].set programs the
-               runtime previously owned.  kind="device".
+               owned by the store; gather reads the K' rows and scatter
+               writes them back in place into the donated stack, so a
+               round never copies the whole stack.  kind="device".
   HostStore    stacked numpy at rest (kind="host"), or numpy memmaps under
                ``mmap_dir`` (kind="mmap"; a "host" store auto-promotes to
                mmap when its at-rest bytes exceed ``mmap_threshold_bytes``).
@@ -54,6 +55,7 @@ import json
 import tempfile
 from collections import OrderedDict
 from dataclasses import asdict, dataclass
+from functools import partial
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -151,6 +153,9 @@ class CohortStore:
         self.k = k
         self._stats = {
             "gathers": 0, "scatters": 0, "h2d_bytes": 0, "d2h_bytes": 0,
+            # scatters that consumed the donated device stack (device
+            # store only: a host write-back has nothing to donate)
+            "scatters_in_place": 0,
             "cache_hits": 0, "cache_misses": 0, "cache_evictions": 0,
             # batched-cache counters (DESIGN.md §13): cohorts assembled by
             # the slot buffer's single gather-by-index, and rows written
@@ -184,7 +189,8 @@ class CohortStore:
     # -- whole-stack access (checkpoints, tests, property access) ---------
 
     def stacked(self) -> Pytree:
-        """The full (K, ...) stacked tree in the at-rest representation."""
+        """The full (K, ...) stacked tree in the at-rest representation:
+        the store's own, valid until the next scatter (use it at once)."""
         raise NotImplementedError
 
     def load_stacked(self, tree: Pytree) -> None:
@@ -279,10 +285,14 @@ class CohortStore:
 class DeviceStore(CohortStore):
     """The seed layout: the (K, ...) stack resident on device.
 
-    Gather/scatter are the jitted take / ``at[ids].set`` programs the
-    runtime owned before §12 — byte-for-byte the same device values, so
+    The store owns its stack, and a round touches only the cohort's K'
+    rows of it: ``gather`` reads each id's row with a dynamic slice, and
+    ``scatter`` writes the rows back in place into the donated stack, so
+    neither program reads, writes or allocates a whole stack.  The values
+    are byte-for-byte those of ``x[ids]`` / ``x.at[ids].set(rows)``, so
     this store IS the baseline the streamed stores are parity-tested
-    against."""
+    against.  A scatter consumes the stack it replaces: ``stacked()`` is a
+    view valid until the next scatter, and ``load_stacked`` copies."""
 
     def __init__(self, cfg: StoreConfig, proto: Pytree, k: int):
         super().__init__(cfg, k)
@@ -291,38 +301,36 @@ class DeviceStore(CohortStore):
             proto,
         )
 
-        # named functions: the XLA modules jit_store_gather /
-        # jit_store_scatter, which a device trace attributes to the store
-        def store_gather(full, ids):
-            return jax.tree.map(lambda x: x[ids], full)
-
-        def store_scatter(full, ids, new):
-            return jax.tree.map(lambda f, n: f.at[ids].set(n), full, new)
-
-        self._gather = jax.jit(store_gather)
-        self._scatter = jax.jit(store_scatter)
-
     def gather(self, ids, shardings=None):
         # shardings are an h2d placement hint; the resident stack already
         # lives where jit wants it, and the engine's in_specs re-lay it out
         self._stats["gathers"] += 1
-        return self._gather(self._stack, jnp.asarray(ids))
+        return store_gather(self._stack, jnp.asarray(ids))
 
     def scatter(self, ids, new_states):
         self._stats["scatters"] += 1
-        self._stack = self._scatter(
+        old = jax.tree.leaves(self._stack)
+        self._stack = store_scatter(
             self._stack, jnp.asarray(ids),
             jax.tree.map(jnp.asarray, new_states),
         )
+        # donation is a request: where the compiler cannot alias (a stack
+        # laid out unlike the program's output, on a mesh) the old leaves
+        # stay alive and the write-back was a copy
+        if all(x.is_deleted() for x in old):
+            self._stats["scatters_in_place"] += 1
 
     def offload(self, tree, force_host=False):
         return jax.device_get(tree) if force_host else tree
 
     def stacked(self):
+        """The resident stack itself: valid until the next scatter, which
+        consumes it."""
         return self._stack
 
     def load_stacked(self, tree):
-        self._stack = jax.tree.map(jnp.asarray, tree)
+        # a copy: the caller's tree must survive the next scatter
+        self._stack = jax.tree.map(lambda x: jnp.array(x, copy=True), tree)
 
     def _host_block(self, lo, hi):
         return jax.tree.map(lambda x: np.asarray(x[lo:hi]), self._stack)
@@ -331,6 +339,43 @@ class DeviceStore(CohortStore):
         flat, treedef = jax.tree_util.tree_flatten(self._stack)
         flat = [f.at[lo:hi].set(jnp.asarray(b)) for f, b in zip(flat, flat_leaves)]
         self._stack = jax.tree_util.tree_unflatten(treedef, flat)
+
+
+# -- device store programs (DESIGN.md §12) ----------------------------------
+#
+# Named so a device trace attributes their XLA modules (jit_store_gather,
+# jit_store_scatter) to the store.  Each touches K' rows of the stack: an
+# XLA gather (``x[ids]``) first rewrites its whole operand, so the gather is
+# one fori_loop over the cohort whose body reads row j of every leaf
+# (compile cost grows with the number of leaves, not with K' x leaves); an
+# undonated ``at[ids].set`` starts with a copy of the stack, so the scatter
+# donates it and XLA updates it in place (tests/test_tpu_compile.py checks
+# the v5e modules).  The scatter is ``at[ids].set``, not a row loop: the
+# TPU lays some narrow leaves out with the client axis minor, and a row
+# loop moves each of them whole for every row.
+# Ids are in range (drawn from arange(K)).
+
+@jax.jit
+def store_gather(full, ids):
+    """(K', ...) cohort: row j of every leaf is ``full[ids[j]]``."""
+    n = ids.shape[0]
+    out = jax.tree.map(lambda x: jnp.zeros((n,) + x.shape[1:], x.dtype), full)
+
+    def body(j, out):
+        i = ids[j]
+        return jax.tree.map(
+            lambda o, x: jax.lax.dynamic_update_index_in_dim(
+                o, jax.lax.dynamic_index_in_dim(x, i, keepdims=False), j, 0),
+            out, full)
+
+    return jax.lax.fori_loop(0, n, body, out)
+
+
+@partial(jax.jit, donate_argnums=0)
+def store_scatter(full, ids, new):
+    """``full.at[ids].set(new)`` on every leaf, in place: the stack is
+    donated."""
+    return jax.tree.map(lambda f, n: f.at[ids].set(n), full, new)
 
 
 class HostStore(CohortStore):

@@ -626,7 +626,8 @@ class Federation:
                                    self.broadcast, uploads)
         # write-back after upload (§12): the host store starts the d2h
         # copies here and overlaps them with the next round's host-side
-        # sampling; the device store applies its jitted at[ids].set.
+        # sampling; the device store writes the K' rows in place into its
+        # donated stack.
         # sync=False: blocking would serialize that overlap, so the span
         # measures submit time only.
         obs.timed("scatter", self.store.scatter, ids, new_states, sync=False)

@@ -132,6 +132,122 @@ class TestGatherScatter:
             np.asarray(s.gather(np.asarray([0]))["w"][0]), 5.0)
 
 
+# one client's state with every leaf kind the methods stack: f32 weights,
+# an int32 counter and a bool flag, the last two scalar per client
+MIXED = {
+    "w": np.arange(24, dtype=np.float32).reshape(2, 3, 4) / 7.0,
+    "rounds_seen": np.int32(0),
+    "has_delta": np.bool_(False),
+}
+
+
+def _mixed_rows(n, seed):
+    """(n, ...) cohort of random MIXED rows."""
+    r = np.random.RandomState(seed)
+    return {
+        "w": jnp.asarray(r.standard_normal((n, 2, 3, 4)).astype(np.float32)),
+        "rounds_seen": jnp.asarray(r.randint(0, 100, n).astype(np.int32)),
+        "has_delta": jnp.asarray(r.rand(n) > 0.5),
+    }
+
+
+def _bitwise_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape
+        np.testing.assert_array_equal(x.view(np.uint8), y.view(np.uint8))
+
+
+class TestDeviceStoreRows:
+    """The device store moves K' rows: a row-wise gather and an in-place
+    scatter into the donated stack, bitwise those of x[ids] / at[ids].set."""
+
+    @pytest.mark.parametrize("ids", [[3, 0, 7], [6], [1, 2, 3, 4, 5, 6, 7, 0]],
+                             ids=["three", "one", "all"])
+    def test_gather_and_scatter_bitwise_vs_reference(self, ids):
+        s = make_store("device", MIXED, 8)
+        s.scatter(np.arange(8), _mixed_rows(8, seed=0))  # distinct rows
+        full = jax.tree.map(np.asarray, s.stacked())
+        ids = np.asarray(ids)
+        _bitwise_equal(s.gather(ids), jax.tree.map(lambda x: x[ids], full))
+        new = _mixed_rows(len(ids), seed=1)
+        s.scatter(ids, new)
+        want = jax.tree.map(lambda f, n: jnp.asarray(f).at[ids].set(n), full, new)
+        _bitwise_equal(s.stacked(), want)
+
+    def test_scatter_consumes_the_previous_stack(self):
+        s = make_store("device", MIXED, 8)
+        for r in range(3):
+            before = jax.tree.leaves(s.stacked())
+            s.scatter(np.asarray([r, 7 - r]), _mixed_rows(2, seed=r))
+            assert all(x.is_deleted() for x in before)
+        st = s.stats()
+        assert st["scatters"] == 3
+        assert st["scatters_in_place"] == st["scatters"]
+
+    def test_load_stacked_copies_the_callers_tree(self):
+        tree = _mixed_rows(8, seed=2)
+        keep = jax.tree.map(np.asarray, tree)
+        s = make_store("device", MIXED, 8)
+        s.load_stacked(tree)
+        for r in range(2):
+            s.scatter(np.asarray([r]), _mixed_rows(1, seed=10 + r))
+        assert not any(x.is_deleted() for x in jax.tree.leaves(tree))
+        _bitwise_equal(tree, keep)
+        assert s.stats()["scatters_in_place"] == 2
+
+    def test_repeated_ids_write_in_cohort_order(self):
+        """The async driver delivers a client at most once per scatter (the
+        scheduler never dispatches a client that is in flight), but a
+        repeated id keeps at[ids].set's order: the last row wins."""
+        ids = np.asarray([5, 2, 5, 5])
+        s = make_store("device", MIXED, 8)
+        full = jax.tree.map(np.asarray, s.stacked())
+        new = _mixed_rows(len(ids), seed=3)
+        s.scatter(ids, new)
+        want = jax.tree.map(lambda f, n: jnp.asarray(f).at[ids].set(n), full, new)
+        _bitwise_equal(s.stacked(), want)
+        _bitwise_equal(_rows(s, [5]), jax.tree.map(lambda n: n[3:4], new))
+
+    def test_host_store_reports_no_in_place_scatters(self):
+        s = _host()
+        s.scatter(np.asarray([1]), {"w": jnp.ones((1, 2, 3)),
+                                    "nest": {"b": jnp.asarray([2.0])}})
+        assert s.stats()["scatters"] == 1
+        assert s.stats()["scatters_in_place"] == 0
+
+    def test_in_place_count_reaches_the_obs_registry(self, tmp_path):
+        from repro.configs.resnet_cifar import SMALL_CNN as CFG
+        from repro.core.baselines import METHODS
+        from repro.data import (FederatedData, dirichlet_partition,
+                                make_class_conditional_images)
+        from repro.fl import Federation, FLRunConfig
+        from repro.fl.runtime import masked_accuracy
+        from repro.models import cnn
+        from repro.obs import ObsConfig, read_metrics
+
+        images, labels = make_class_conditional_images(
+            200, CFG.n_classes, CFG.cnn_image_size, seed=0)
+        parts = dirichlet_partition(labels, 4, alpha=0.3, seed=0)
+        data = FederatedData.from_partition(images, labels, parts, seed=0)
+        params = cnn.init_params(jax.random.PRNGKey(0), CFG)
+        tdir = tmp_path / "t"
+        cfg = FLRunConfig(n_clients=4, participation=0.5, rounds=2, batch=8,
+                          local_iters=1, seed=0,
+                          obs=ObsConfig(trace_dir=str(tdir), level="round",
+                                        quiet=True))
+        fed = Federation(METHODS["pfedsop"](),
+                         lambda p, b: cnn.loss_fn(p, CFG, b),
+                         masked_accuracy(lambda p, t: cnn.apply(p, CFG, t["images"])),
+                         params, data, cfg)
+        fed.run()
+        gauges = read_metrics(tdir / "metrics.jsonl")[-1]["gauges"]
+        assert gauges["store.scatters"] == 2
+        assert gauges["store.scatters_in_place"] == 2
+
+
 class TestLRUCache:
     def test_eviction_order_is_least_recently_used(self):
         s = _host(cache_clients=2)

@@ -12,6 +12,8 @@ one process may load the TPU compiler library, and under pytest-xdist every
 worker imports this file while only the worker that runs it loads the
 library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.fl.cohort_store import store_gather, store_scatter
 from repro.kernels.flash_gqa.ops import flash_gqa
 from repro.kernels.pfedsop_update.ops import (
     pfedsop_update_batched,
@@ -29,6 +32,8 @@ from repro.kernels.rmsnorm.ops import rmsnorm
 RESNET9_PARAMS = 1_249_956  # RESNET9_CIFAR100
 RESNET18_PARAMS = 4_901_450  # RESNET18_CIFAR10
 COHORT = 20  # K' = 20% of K = 100 (paper Sec. V-B4)
+# the cross-device cell's client stack: K=125, K'=10, its largest leaf
+STORE_K, STORE_COHORT, STORE_LEAF = 125, 10, (3, 3, 512, 512)
 # gemma3-1b attention (repro.configs.gemma3_1b) at a 4k sequence
 B, S, H, KV, D, WINDOW = 1, 4096, 4, 1, 256, 512
 
@@ -98,3 +103,55 @@ def test_flash_gqa_compiles(one_chip, window, grad):
     text = _compiled_text(fn, bf16(B, S, H, D), bf16(B, S, KV, D), bf16(B, S, KV, D))
     # forward kernel; with grad the residual forward + dq and dk/dv passes
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+def _instructions(text, computation="ENTRY"):
+    """(result type, opcode) of every instruction of one computation of a
+    compiled module's text."""
+    out, inside = [], False
+    for line in text.splitlines():
+        if not inside:
+            inside = line.startswith(computation)
+            continue
+        if line.startswith("}"):
+            break
+        m = re.match(r"\s*(?:ROOT\s+)?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if m:
+            out.append(m.groups())
+    return out
+
+
+def _store_shapes(sharding):
+    """A stack (K, ...) and cohort (K', ...) of one big f32 leaf and a
+    scalar-per-client int32 leaf, with the cohort's ids."""
+    def tree(n):
+        return {"w": jax.ShapeDtypeStruct((n,) + STORE_LEAF, jnp.float32, sharding=sharding),
+                "seen": jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding)}
+    ids = jax.ShapeDtypeStruct((STORE_COHORT,), jnp.int32, sharding=sharding)
+    return tree(STORE_K), tree(STORE_COHORT), ids
+
+
+def test_store_scatter_writes_in_place(one_chip):
+    """The device store's write-back aliases the donated stack and copies
+    none of it."""
+    full, new, ids = _store_shapes(one_chip)
+    text = store_scatter.lower(full, ids, new).compile().as_text()
+    header = text.splitlines()[0]
+    assert "input_output_alias" in header
+    assert header.count("-alias)") == len(jax.tree.leaves(full))
+    stack = f"f32[{STORE_K},"
+    copies = [line for line in text.splitlines()
+              if re.search(r" copy(-start)?\(", line) and stack in line.split(" copy")[0]]
+    assert not copies, copies
+
+
+def test_store_gather_reads_only_the_cohort_rows(one_chip):
+    """The device store's gather holds no stack-sized operation but its
+    parameter and the loop that reads rows from it (an XLA gather first
+    rewrites its whole operand)."""
+    full, _, ids = _store_shapes(one_chip)
+    text = store_gather.lower(full, ids).compile().as_text()
+    stack = f"f32[{STORE_K},"
+    ops = [op for shape, op in _instructions(text) if stack in shape]
+    assert "parameter" in ops
+    assert set(ops) <= {"parameter", "tuple", "while", "get-tuple-element"}, ops
