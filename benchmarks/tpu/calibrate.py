@@ -9,9 +9,9 @@ own size, all in one process:
   matmul precision against the reference at ``highest``: where a gap
   stays, it is not the rounding of the chip's default precision;
 - with ``--faults``, faults planted in the program on the first
-  ``--control`` seeds: half of each local batch left out (the loss is the
-  mean over the other half) and the eval's answers altered (each
-  prediction moved to the next class).
+  ``--control`` seeds: half of each local batch left out (the family's
+  program loss is the mean over the other half) and the eval's answers
+  altered (each prediction moved to the next class).
 
   python3 benchmarks/tpu/calibrate.py --workload <cell> --seeds 1 2 3 ... \\
       [--control 3] [--faults] [--highest]
@@ -34,26 +34,33 @@ from tpubench import check, devices as devs, spec
 
 
 @contextlib.contextmanager
-def half_batch():
-    """Local SGD's loss over the first half of each batch only."""
-    from repro.models import cnn
+def half_batch(family):
+    """Local SGD's loss, the family's program loss, over the first half of
+    each batch only."""
+    import jax
 
-    orig = cnn.loss_fn
+    orig = family.program
 
-    def loss(params, cfg, batch):
-        half = batch["labels"].shape[0] // 2
-        return orig(params, cfg, {k: v[:half] for k, v in batch.items()})
+    def program(config):
+        loss_fn, acc_fn = orig(config)
 
-    cnn.loss_fn = loss
+        def loss(params, batch):
+            half = jax.tree.leaves(batch)[0].shape[0] // 2
+            return loss_fn(params, jax.tree.map(lambda v: v[:half], batch))
+
+        return loss, acc_fn
+
+    family.program = program
     try:
         yield
     finally:
-        cnn.loss_fn = orig
+        family.program = orig
 
 
 @contextlib.contextmanager
-def altered_answers():
-    """The eval's predictions each moved to the next class."""
+def altered_answers(family):
+    """The eval's predictions each moved to the next class, in the
+    program's masked eval (``repro.fl.runtime.masked_accuracy``)."""
     import jax.numpy as jnp
     from repro.fl import runtime
 
@@ -83,15 +90,15 @@ def leaves(side: dict) -> dict:
             "params": side["state3"]["params"], "delta": side["state3"]["delta"]}
 
 
-def program_side(config, traffic, seed, fault=None, precision=None):
+def program_side(config, traffic, seed, family, fault=None, precision=None):
     import jax
 
-    with (FAULTS[fault]() if fault else contextlib.nullcontext()), \
+    with (FAULTS[fault](family) if fault else contextlib.nullcontext()), \
             (jax.default_matmul_precision(precision) if precision
              else contextlib.nullcontext()):
-        cell = run.Cell(config, traffic, seed)
+        cell = run.Cell(config, traffic, seed, family)
         prog = run.warm_up(cell)
-    data = (cell.images, cell.labels, cell.parts, cell.weights)
+    data = (cell.bank, cell.parts, cell.weights)
     del cell
     gc.collect()
     return prog, data
@@ -111,6 +118,7 @@ def main() -> int:
     bench = spec.load_benchmark()
     wl = spec.workload(bench, args.workload)
     config = spec.load_config(bench, wl["config"])
+    family = spec.load_family(config.get("family"))
     traffic = spec.load_traffic(wl["traffic"])
 
     import jax
@@ -121,16 +129,16 @@ def main() -> int:
     enable_compile_cache()
     for i, seed in enumerate(args.seeds):
         t0 = time.perf_counter()
-        prog, (images, labels, parts, weights) = program_side(config, traffic, seed)
+        prog, (bank, parts, weights) = program_side(config, traffic, seed, family)
         weights = jax.device_get(weights)
-        ref = run.reference_readings(config, traffic, seed, images, labels, parts, weights)
+        ref = run.reference_readings(config, traffic, seed, family, bank, parts, weights)
         rows = [("program", check.readings(prog, ref))]
         sides = {"program": prog}
         run.log(f"worst update1 leaves: {check.leaf_gaps(prog['update1'], ref['update1'])}")
         if i < args.control and args.highest:
-            hi = run.reference_readings(config, traffic, seed, images, labels, parts,
+            hi = run.reference_readings(config, traffic, seed, family, bank, parts,
                                         weights, precision="highest")
-            prog_hi, _ = program_side(config, traffic, seed, precision="highest")
+            prog_hi, _ = program_side(config, traffic, seed, family, precision="highest")
             rows.append(("highest_vs_highest", check.readings(prog_hi, hi)))
             sides["program_highest"], sides["reference_highest"] = prog_hi, hi
             run.log("worst update1 leaves, both at highest: "
@@ -139,12 +147,12 @@ def main() -> int:
                 run.log(f"worst state3 {part} leaves, both at highest: "
                         f"{check.leaf_gaps(prog_hi['state3'][part], hi['state3'][part])}")
         if i < args.control:
-            ctrl = run.reference_readings(config, traffic, seed, images, labels, parts,
+            ctrl = run.reference_readings(config, traffic, seed, family, bank, parts,
                                           weights, dtype="bfloat16")
             rows.append(("control_bf16", check.readings(ctrl, ref)))
             sides["control_bf16"] = ctrl
             for fault in (FAULTS if args.faults else ()):
-                bad, _ = program_side(config, traffic, seed, fault)
+                bad, _ = program_side(config, traffic, seed, family, fault)
                 rows.append((fault, check.readings(bad, ref)))
                 sides[fault] = bad
         for side, numbers in rows:

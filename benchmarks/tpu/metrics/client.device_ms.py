@@ -5,5 +5,5 @@
 def read(ctx):
     from tpubench import program_trace as pt
 
-    stretch = pt.ensure(ctx)
+    stretch = ctx.get("stretch_d")
     return None if stretch is None else pt.module_ms(stretch, pt.CLIENT)

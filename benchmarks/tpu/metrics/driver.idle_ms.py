@@ -6,5 +6,5 @@ work causes), in stretch (D), spans and device on one clock."""
 def read(ctx):
     from tpubench import program_trace as pt
 
-    stretch = pt.ensure(ctx)
+    stretch = ctx.get("stretch_d")
     return None if stretch is None else pt.driver_idle_ms(stretch)

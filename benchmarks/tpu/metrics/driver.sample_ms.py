@@ -5,5 +5,5 @@ batch and test-set gathers in numpy), in stretch (D)."""
 def read(ctx):
     from tpubench import program_trace as pt
 
-    stretch = pt.ensure(ctx)
+    stretch = ctx.get("stretch_d")
     return None if stretch is None else pt.span_ms(stretch, "sample")

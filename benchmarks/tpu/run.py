@@ -4,7 +4,9 @@
   python3 benchmarks/tpu/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell, its configuration and traffic mix are named in BENCHMARK.json
-and found as files: ``configs/<config>.json``, ``traffic/<traffic>.json``,
+and found as files: ``configs/<config>.json``, the model family the
+configuration names (``families/<family>.py``: the program's model, its
+sample bank, work counts and plain reference), ``traffic/<traffic>.json``,
 ``limits/<cell>.json`` and one ``metrics/<metric>.py`` reader per
 per-layer metric.  Set-up builds one ``repro.fl.Federation`` from the seed
 and drives it through three rounds (round 0 compiles; the later two show
@@ -13,8 +15,9 @@ rounds to decide ``correct``.  The same federation then runs whole rounds
 until ``--seconds`` have passed.  With ``--trace 1`` that time is split
 into stretches: one under the JAX profiler recording device operations
 only, a short one with the host tracer on (for the breakdown of idle
-gaps), and one with the program's phase spans on; the per-layer metrics
-are read from the first and the last.
+gaps), one with the program's phase spans on, and one under the device
+profiler with the program's round spans on its clock; the per-layer
+metrics are read from all but the second.
 
 The last stdout line is the result's JSON object.  A host without a TPU,
 with too few chips or with a chip missing from the peak table, exits 3
@@ -28,7 +31,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -40,8 +42,7 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
 
-from tpubench import check, devices as devs, profile, spec, work  # noqa: E402
-from tpubench import traffic as traffic_gen  # noqa: E402
+from tpubench import check, devices as devs, profile, program_trace, spec, work  # noqa: E402
 
 WARM_ROUNDS = 3       # rounds the reference replays; round 0 compiles
 PHASES = ("gather", "client", "all_gather", "eval", "aggregate", "scatter")
@@ -81,49 +82,22 @@ class CompileCounter:
                 f"cache_hits={self.hits} cache_misses={self.misses}")
 
 
-def program_model(config: dict):
-    """The program's ModelConfig at the sizes the configuration file
-    states: the named program config with its channels, image size, input
-    channels and classes replaced.  The program's ResNet has one basic
-    block per stage and no option for more, so a file that states another
-    count is refused."""
-    import dataclasses
-
-    mod, attr = config["program_config"].split(":")
-    base = getattr(importlib.import_module(mod), attr)
-    model = config["model"]
-    if model.get("blocks_per_stage", 1) != 1:
-        raise spec.SpecError(f"{config['name']} states blocks_per_stage "
-                             f"{model['blocks_per_stage']}; the program's ResNet has "
-                             "one block per stage")
-    return dataclasses.replace(
-        base, name=config["name"], cnn_channels=tuple(model["channels"]),
-        cnn_image_size=model["image_size"], cnn_in_channels=model["in_channels"],
-        n_classes=model["n_classes"])
-
-
 class Cell:
     """One federation built from the seed, as the window drives it."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, config: dict, traffic: dict, seed: int, family):
         from repro.core import baselines as bl
         from repro.core.pfedsop import PFedSOPConfig
-        from repro.data import FederatedData
         from repro.fl import FLRunConfig, Federation
-        from repro.fl.runtime import masked_accuracy
-        from repro.models import cnn
-        from tpubench.reference import make_weights
 
-        self.config, self.traffic, self.seed = config, traffic, seed
-        model_cfg = program_model(config)
-        log(f"[{time.perf_counter() - T_START:.2f}s] generating the image bank")
-        self.images, self.labels, parts = traffic_gen.generate(config, traffic, seed)
+        self.config, self.traffic, self.seed, self.family = config, traffic, seed, family
+        loss_fn, acc_fn = family.program(config)
+        log(f"[{time.perf_counter() - T_START:.2f}s] generating the sample bank")
+        self.bank, parts = family.generate(config, traffic, seed)
         log(f"[{time.perf_counter() - T_START:.2f}s] building the federation")
         self.parts = [p.copy() for p in parts]     # the program shuffles its own
-        data = FederatedData.from_partition(self.images, self.labels, parts,
-                                            train_frac=config["data"]["train_frac"],
-                                            seed=seed)
-        self.weights = make_weights(seed, config["model"])
+        data = family.federated_data(self.bank, parts, config, seed)
+        self.weights = family.make_weights(seed, config)
         m = config["method"]
         method = bl.PFedSOP(cfg=PFedSOPConfig(eta1=m["eta1"], eta2=m["eta2"],
                                               rho=m["rho"], lam=m["lam"]))
@@ -133,10 +107,7 @@ class Cell:
             rounds=1, batch=traffic["batch"], local_iters=traffic["local_iters"],
             seed=seed, backend=run["backend"], mesh=run["mesh"], store=run["store"],
             update_impl=m["update_impl"])
-        self.fed = Federation(
-            method, lambda p, b: cnn.loss_fn(p, model_cfg, b),
-            masked_accuracy(lambda p, t: cnn.apply(p, model_cfg, t["images"])),
-            self.weights, data, self.run_cfg)
+        self.fed = Federation(method, loss_fn, acc_fn, self.weights, data, self.run_cfg)
         self.test_counts = data.test_counts
         log(f"[{time.perf_counter() - T_START:.2f}s] federation built")
 
@@ -153,10 +124,10 @@ class Cell:
     def model_flops(self, rounds: int, eval_samples: int) -> int:
         """Model FLOPs of ``rounds`` rounds whose cohorts held
         ``eval_samples`` real test samples in all."""
-        model = self.config["model"]
-        return (rounds * work.round_model_flops(model, self.fed.kprime, self.fed.T,
+        fwd = self.family.forward_flops(self.config)
+        return (rounds * work.round_model_flops(fwd, self.fed.kprime, self.fed.T,
                                                 self.run_cfg.batch, 0)
-                + work.forward_flops(model) * eval_samples)
+                + fwd * eval_samples)
 
     def sync(self):
         import jax
@@ -231,8 +202,9 @@ def timed_rounds(cell: Cell, seconds: float, annotate: bool = False,
     return n, elapsed, bad, eval_samples
 
 
-# shares of a traced run's --seconds: (P) device trace, (H) host trace, (S) spans
-STRETCHES = {"P": 0.45, "H": 0.15, "S": 0.40}
+# shares of a traced run's --seconds: (P) device trace, (H) host trace, (S)
+# phase spans, (D) round spans on the device trace's clock
+STRETCHES = {"P": 0.45, "H": 0.15, "S": 0.40, "D": program_trace.SHARE}
 
 
 def profiled(cell: Cell, seconds: float, host_level: int, label: str):
@@ -255,14 +227,16 @@ def profiled(cell: Cell, seconds: float, host_level: int, label: str):
 
 
 def traced_stretches(cell: Cell, seconds: float, chips: int, kind: str):
-    """Three stretches of whole rounds; returns the readers' context.
+    """Four stretches of whole rounds; returns the readers' context.
 
     (P) the profiler records device operations only, since the host
     tracer slows the host's part of every round.  Device busy time,
     kernel time and model FLOPs are read here, over the stretch's own
     length on the host clock.  (H) a short stretch with the host tracer
     on names the device's idle gaps by what the host was doing, for the
-    breakdown only.  (S) the program's phase spans, no profiler."""
+    breakdown only.  (S) the program's phase spans, no profiler.  (D) the
+    program's round spans under a device-only profile
+    (``tpubench/program_trace.py``)."""
     from repro.obs import Obs, ObsConfig, read_events
 
     ctx = {"chips": chips, "peak": devs.PEAKS[kind]}
@@ -273,7 +247,9 @@ def traced_stretches(cell: Cell, seconds: float, chips: int, kind: str):
                model_flops=cell.model_flops(n, eval_samples),
                busy_s=profile.device_busy_s(events, -inf, inf),
                update_work=work.update_work(
-                   cell.fed.kprime // chips, work.param_count(cell.config["model"])))
+                   cell.fed.kprime // chips, cell.family.param_count(cell.config)))
+    log(f"stretch P work: model_flops={ctx['model_flops']} over {n} rounds and "
+        f"{eval_samples} eval samples, update_work={ctx['update_work']}")
 
     host_events, (n_h, _, bad_h, _) = profiled(
         cell, seconds * STRETCHES["H"], 1, "stretch H (host trace)")
@@ -302,14 +278,18 @@ def traced_stretches(cell: Cell, seconds: float, chips: int, kind: str):
         per_round.append(phase)
     ctx["spans"] = per_round
     ctx["obs_phases"] = sorted({e["name"] for e in spans})
-    return ctx, n + n_h + n_s, bad + bad_h + bad_s
+
+    stretch_d = program_trace.run_stretch(cell, seconds * STRETCHES["D"], timed_rounds)
+    program_trace.describe(stretch_d)
+    ctx["stretch_d"] = stretch_d
+    return ctx, n + n_h + n_s + stretch_d["rounds"], bad + bad_h + bad_s + stretch_d["bad"]
 
 
-def reference_readings(cell_config: dict, traffic: dict, seed: int, images, labels,
+def reference_readings(cell_config: dict, traffic: dict, seed: int, family, bank,
                        parts, weights, dtype="float32", precision=None) -> dict:
     """The reference's side of the readings: three rounds replayed from
-    the seed, in ``dtype`` at the matmul precision the configuration
-    states (``bfloat16``: the control)."""
+    the seed with the family's plain model, in ``dtype`` at the matmul
+    precision the configuration states (``bfloat16``: the control)."""
     import jax
     import jax.numpy as jnp
     from tpubench.reference import ClientSplit, Hyper, ReferenceFederation, leaf_norms
@@ -319,10 +299,11 @@ def reference_readings(cell_config: dict, traffic: dict, seed: int, images, labe
     k = traffic["clients"]
     kprime = max(1, int(round(traffic["participation"] * k)))
     iters = traffic["local_iters"] or split.local_iters(traffic["batch"])
-    with jax.default_matmul_precision(precision or cell_config["precision"]["conv_matmul"]):
-        ref = ReferenceFederation(images, labels, split, weights,
+    loss, correct = family.reference(cell_config)
+    with jax.default_matmul_precision(precision or cell_config["precision"]["matmul"]):
+        ref = ReferenceFederation(bank, split, weights,
                                   Hyper(m["eta1"], m["eta2"], m["rho"], m["lam"]),
-                                  kprime, iters, traffic["batch"], seed,
+                                  kprime, iters, traffic["batch"], seed, loss, correct,
                                   dtype=jnp.dtype(dtype))
         out = {"loss": [], "acc": []}
         for r in range(WARM_ROUNDS):
@@ -336,15 +317,18 @@ def reference_readings(cell_config: dict, traffic: dict, seed: int, images, labe
 
 
 def run_cell(name: str, config: dict, traffic: dict, limits: dict, seed: int,
-             seconds: float, trace: bool, devices, metric_specs, readers=None) -> dict:
-    """One run of one cell on ``devices``; returns the result object."""
+             seconds: float, trace: bool, devices, metric_specs, family,
+             readers=None) -> dict:
+    """One run of one cell of the model ``family`` on ``devices``; returns
+    the result object."""
     import jax
 
     counter = CompileCounter()
-    cell = Cell(config, traffic, seed)
+    cell = Cell(config, traffic, seed, family)
     log(f"cell {name}: K={traffic['clients']} K'={cell.fed.kprime} T={cell.fed.T} "
         f"B={traffic['batch']} engine={cell.fed.engine.describe()} "
-        f"store={cell.fed.store.describe()}")
+        f"store={cell.fed.store.describe()} forward_flops={family.forward_flops(config)} "
+        f"param_count={family.param_count(config)}")
     prog = warm_up(cell)
     setup_s = time.perf_counter() - T_START
     log(f"set-up {setup_s:.3f}s: {counter}")
@@ -377,12 +361,11 @@ def run_cell(name: str, config: dict, traffic: dict, limits: dict, seed: int,
               **device_extra}
 
     # free the program's state before the reference runs
-    images, labels, parts, weights = cell.images, cell.labels, cell.parts, cell.weights
-    weights = jax.device_get(weights)
+    bank, parts, weights = cell.bank, cell.parts, jax.device_get(cell.weights)
     del cell
     gc.collect()
     t0 = time.perf_counter()
-    ref = reference_readings(config, traffic, seed, images, labels, parts, weights)
+    ref = reference_readings(config, traffic, seed, family, bank, parts, weights)
     numbers = check.readings(prog, ref)
     correct, table = check.judge(numbers, limits)
     log(f"reference: {time.perf_counter() - t0:.3f}s; "
@@ -416,6 +399,7 @@ def main(argv=None) -> int:
     bench = spec.load_benchmark()
     wl = spec.workload(bench, args.workload)
     config = spec.load_config(bench, wl["config"])
+    family = spec.load_family(config.get("family"))
     traffic = spec.load_traffic(wl["traffic"])
     limits = spec.load_limits(wl["name"])
     metric_specs = spec.cell_metrics(bench, wl["name"], bool(args.trace))
@@ -440,7 +424,7 @@ def main(argv=None) -> int:
     # 32 bits; a larger seed is folded into them
     seed = args.seed % 2**32
     result = run_cell(wl["name"], config, traffic, limits, seed, args.seconds,
-                      bool(args.trace), devices, metric_specs, readers)
+                      bool(args.trace), devices, metric_specs, family, readers)
     print(json.dumps(result), flush=True)
     return 0
 

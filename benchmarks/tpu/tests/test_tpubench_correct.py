@@ -19,6 +19,7 @@ from tpubench import check, spec  # noqa: E402
 CELL = "resnet18-cifar100.crossdevice"
 SPECS = [{"name": "rounds_per_s", "unit": "rounds/s"}, {"name": "setup_s", "unit": "s"}]
 SEED = 2**31 + 12345
+RESNET = spec.load_family("resnet")
 
 
 def tiny():
@@ -40,7 +41,7 @@ def run_tiny():
 
     config, traffic, limits = tiny()
     return run.run_cell(CELL, config, traffic, limits, SEED, 0.01, False,
-                        jax.devices(), SPECS)
+                        jax.devices(), SPECS, RESNET)
 
 
 def test_sound_run_is_correct():
@@ -52,9 +53,9 @@ def test_sound_run_is_correct():
 
 def test_bfloat16_control_is_not_correct():
     config, traffic, limits = tiny()
-    _, (images, labels, parts, weights) = calibrate.program_side(config, traffic, SEED)
-    ref = run.reference_readings(config, traffic, SEED, images, labels, parts, weights)
-    ctrl = run.reference_readings(config, traffic, SEED, images, labels, parts, weights,
+    _, (bank, parts, weights) = calibrate.program_side(config, traffic, SEED, RESNET)
+    ref = run.reference_readings(config, traffic, SEED, RESNET, bank, parts, weights)
+    ctrl = run.reference_readings(config, traffic, SEED, RESNET, bank, parts, weights,
                                   dtype="bfloat16")
     correct, table = check.judge(check.readings(ctrl, ref), limits)
     assert not correct, table
@@ -74,7 +75,7 @@ def unchanged_state(monkeypatch):
 
 def planted(fault):
     def plant(monkeypatch):
-        cm = calibrate.FAULTS[fault]()
+        cm = calibrate.FAULTS[fault](RESNET)
         cm.__enter__()
         monkeypatch.undo = (lambda undo=monkeypatch.undo: (cm.__exit__(None, None, None),
                                                            undo()))

@@ -92,27 +92,7 @@ def test_readers_outside_the_harness_run_no_stretch():
     ctx = {}
     for m in NEW:
         assert spec.load_reader(m)(ctx) is None
-    assert ctx == {"stretch_d": None}
-
-
-class _Fed:
-    """A federation without ``attach_obs``, as the parent program's."""
-
-
-class _Cell:
-    fed = _Fed()
-
-
-def run_cell(cell, seconds, ctx):
-    """Stands in for the harness's frame."""
-    return pt.ensure(ctx)
-
-
-def test_a_program_without_attach_obs_gets_no_stretch(capsys):
-    ctx = {}
-    assert run_cell(_Cell(), 40.0, ctx) is None
-    assert ctx == {"stretch_d": None}
-    assert "no Federation.attach_obs" in capsys.readouterr().err
+    assert ctx == {}
 
 
 def recorded():

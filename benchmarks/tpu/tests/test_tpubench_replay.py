@@ -11,7 +11,6 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import run  # noqa: E402
-from run import timed_rounds  # noqa: E402,F401  (the harness frame's global)
 from tpubench import program_trace as pt  # noqa: E402
 
 
@@ -55,12 +54,6 @@ def test_replay_matches_the_cohort_each_round_reports(seed):
         assert m["eval_samples"] == int(cell.test_counts[m["clients"]].sum())
 
 
-def run_cell(cell, seconds, ctx):
-    """Stands in for the harness's frame, whose globals hold
-    ``timed_rounds``."""
-    return pt.ensure(ctx)
-
-
 def test_stretch_d_attaches_round_spans_and_detaches(capsys):
     """On the CPU the trace has no TPU plane, so nothing is read from the
     device; the stretch still runs whole rounds with the program's spans,
@@ -69,9 +62,9 @@ def test_stretch_d_attaches_round_spans_and_detaches(capsys):
 
     cell = SmallCell(1)
     cell.fed.run_round()                      # compile outside the stretch
-    ctx = {}
-    st = run_cell(cell, 0.5 / pt.SHARE, ctx)
-    assert ctx["stretch_d"] is st and st["rounds"] >= 1
+    st = pt.run_stretch(cell, 0.5, run.timed_rounds)
+    pt.describe(st)
+    assert st["rounds"] >= 1 and st["bad"] == 0
     assert cell.fed.obs is NOOP and cell.fed.programs.obs is NOOP
     names = [s[0] for s in st["spans"]]
     per_round = ["sample", "dispatch.gather", "dispatch.client", "dispatch.eval",

@@ -28,11 +28,8 @@ def test_cell_files_load_by_name(cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_configuration_matches_program_model(cell):
-    sys.path.insert(0, str(spec.BENCH_DIR))
-    import run
-
     config = spec.load_config(BENCH, spec.workload(BENCH, cell)["config"])
-    cfg = run.program_model(config)
+    cfg = spec.load_family(config["family"]).program_model(config)
     model = config["model"]
     assert cfg.family == "cnn" and cfg.name == config["name"]
     assert (list(cfg.cnn_channels), cfg.cnn_image_size, cfg.cnn_in_channels, cfg.n_classes) \
@@ -42,14 +39,11 @@ def test_configuration_matches_program_model(cell):
 def test_mismatched_configuration_is_refused():
     """The program's ResNet has one block per stage: a file that states
     two cannot be run as stated."""
-    sys.path.insert(0, str(spec.BENCH_DIR))
-    import run
-
     config = spec.load_config(BENCH, "resnet18-cifar10")
     config = json.loads(json.dumps(config))
     config["model"]["blocks_per_stage"] = 2
     with pytest.raises(spec.SpecError):
-        run.program_model(config)
+        spec.load_family(config["family"]).program_model(config)
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
@@ -85,6 +79,30 @@ def test_span_readers_average_over_rounds():
     assert spec.load_reader("eval.ms")(ctx) == 3.0
     assert spec.load_reader("store.ms")(ctx) == 2.25
     assert spec.load_reader("driver.host_ms")(ctx) == pytest.approx((6.25 + 10.25) / 2)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_every_configuration_names_a_family(config):
+    family = spec.load_family(spec.load_config(BENCH, config)["family"])
+    assert all(callable(getattr(family, f)) for f in spec.FAMILY_API)
+
+
+@pytest.mark.parametrize("name", [None, "no-such-family", "../families/resnet", ""])
+def test_unknown_family_is_a_spec_error(name):
+    with pytest.raises(spec.SpecError):
+        spec.load_family(name)
+
+
+def test_missing_family_file_is_a_spec_error(tmp_path):
+    with pytest.raises(spec.SpecError, match="missing"):
+        spec.load_family("resnet", bench_dir=tmp_path)
+
+
+def test_family_without_the_whole_interface_is_a_spec_error(tmp_path):
+    (tmp_path / "families").mkdir()
+    (tmp_path / "families" / "half.py").write_text("def program(config):\n    pass\n")
+    with pytest.raises(spec.SpecError, match="lacks generate"):
+        spec.load_family("half", bench_dir=tmp_path)
 
 
 def test_missing_files_are_errors(tmp_path):

@@ -11,7 +11,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from tpubench import profile, spec, work  # noqa: E402
 
-RESNET9 = dict(image_size=32, channels=[64, 128, 256], in_channels=3, n_classes=100)
+RESNET9 = {"model": dict(image_size=32, channels=[64, 128, 256], in_channels=3,
+                         n_classes=100)}
 
 RECORDED = Path(__file__).resolve().parent / "data" / "trace_excerpt.json"
 
@@ -106,7 +107,7 @@ def test_recorded_trace_reduces_within_bounds():
     assert busy < nested_sum          # nested ops counted once
     ctx = {"events": ev, "lo": lo, "hi": hi, "rounds": 1, "window_s": (hi - lo) / 1e9,
            "peak": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-           "update_work": work.update_work(10, work.param_count(RESNET9))}
+           "update_work": work.update_work(10, spec.load_family("resnet").param_count(RESNET9))}
     share = spec.load_reader("pfedsop_update_roofline")(ctx)
     # 156 MB at 819 GB/s over the 216 us the three calls took
     assert share == pytest.approx(88.0, abs=1.0)

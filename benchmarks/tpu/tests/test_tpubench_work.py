@@ -1,4 +1,5 @@
-"""Work counts of the benchmark against hand counts and the program's shapes."""
+"""Work counts of the benchmark (the ResNet family's, ``families/resnet.py``,
+and the round's) against hand counts and the program's shapes."""
 import sys
 from pathlib import Path
 
@@ -9,13 +10,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[3] / "src"))
 
 from tpubench import spec, work  # noqa: E402
 
-RESNET9 = dict(image_size=32, channels=[64, 128, 256], in_channels=3, n_classes=100)
-RESNET18 = dict(image_size=32, channels=[64, 128, 256, 512], in_channels=3, n_classes=10)
+RESNET = spec.load_family("resnet")
+RESNET9 = {"model": dict(image_size=32, channels=[64, 128, 256], in_channels=3,
+                         n_classes=100)}
+RESNET18 = {"model": dict(image_size=32, channels=[64, 128, 256, 512], in_channels=3,
+                          n_classes=10)}
 
 
 @pytest.mark.parametrize("model,gflop", [(RESNET9, 0.389), (RESNET18, 0.507)])
 def test_forward_flops_match_hand_count(model, gflop):
-    assert work.forward_flops(model) / 1e9 == pytest.approx(gflop, abs=5e-4)
+    assert RESNET.forward_flops(model) / 1e9 == pytest.approx(gflop, abs=5e-4)
 
 
 def test_resnet9_flops_exact():
@@ -23,7 +27,7 @@ def test_resnet9_flops_exact():
     macs = (32 * 32 * 27 * 64 + 2 * 32 * 32 * 576 * 64
             + 16 * 16 * 576 * 128 + 16 * 16 * 1152 * 128 + 16 * 16 * 64 * 128
             + 8 * 8 * 1152 * 256 + 8 * 8 * 2304 * 256 + 8 * 8 * 128 * 256 + 256 * 100)
-    assert work.forward_flops(RESNET9) == 2 * macs
+    assert RESNET.forward_flops(RESNET9) == 2 * macs
 
 
 @pytest.mark.parametrize("name,model", [("RESNET9_CIFAR100", RESNET9),
@@ -37,30 +41,33 @@ def test_param_count_matches_program_model(name, model):
     params = jax.eval_shape(lambda: cnn.init_params(jax.random.PRNGKey(0),
                                                     getattr(resnet_cifar, name)))
     n = sum(x.size for x in jax.tree.leaves(params))
-    assert work.param_count(model) == n
+    assert RESNET.param_count(model) == n
 
 
 @pytest.mark.parametrize("config", [c["name"] for c in spec.load_benchmark()["configs"]])
 def test_param_count_matches_each_configuration_as_run(config):
     import jax
 
-    import run
     from repro.models import cnn
 
     cfg = spec.load_config(spec.load_benchmark(), config)
+    family = spec.load_family(cfg["family"])
     params = jax.eval_shape(lambda: cnn.init_params(jax.random.PRNGKey(0),
-                                                    run.program_model(cfg)))
+                                                    family.program_model(cfg)))
     n = sum(x.size for x in jax.tree.leaves(params))
-    assert work.param_count(cfg["model"]) == n
+    assert family.param_count(cfg) == n
+    # the weights the family makes are as large
+    weights = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg["model"]))
+    assert sum(x.size for x in jax.tree.leaves(weights)) == n
 
 
 def test_round_model_flops_counts_sgd_three_times_and_real_eval_once():
-    fwd = work.forward_flops(RESNET9)
-    assert work.round_model_flops(RESNET9, 20, 10, 50, 0) == 3 * fwd * 20 * 10 * 50
-    assert (work.round_model_flops(RESNET9, 20, 10, 50, 2400)
-            - work.round_model_flops(RESNET9, 20, 10, 50, 0)) == fwd * 2400
+    fwd = RESNET.forward_flops(RESNET9)
+    assert work.round_model_flops(fwd, 20, 10, 50, 0) == 3 * fwd * 20 * 10 * 50
+    assert (work.round_model_flops(fwd, 20, 10, 50, 2400)
+            - work.round_model_flops(fwd, 20, 10, 50, 0)) == fwd * 2400
     # the paper round: 11.7 TFLOP of local SGD
-    assert work.round_model_flops(RESNET9, 20, 10, 50, 0) / 1e12 == pytest.approx(11.68, abs=0.01)
+    assert work.round_model_flops(fwd, 20, 10, 50, 0) / 1e12 == pytest.approx(11.68, abs=0.01)
 
 
 def test_update_work_reads_shared_delta_once():

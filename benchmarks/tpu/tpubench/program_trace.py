@@ -21,11 +21,9 @@ The reduced trace, which the tests read back from a recorded excerpt:
    "ops":     {chip: [[op name, start ns, duration ns], ...]},   # XLA Ops
    "modules": {chip: [[module name, start ns, duration ns], ...]}}
 
-The harness (``run.py``) runs stretches P, H and S only, so stretch D
-is run by the first of its readers (``ensure``): it finds the window's
-cell and ``--seconds`` in the harness's ``run_cell`` frame, which calls
-the readers after those stretches and before the cell is freed.  A program without ``Federation.attach_obs`` gets no stretch,
-and the readers find nothing.
+The harness (``run.py``, ``traced_stretches``) runs stretch D after S
+and hands it to the readers as ``ctx["stretch_d"]``; a reader that finds
+no stretch there returns nothing.
 """
 from __future__ import annotations
 
@@ -169,7 +167,8 @@ def run_stretch(cell, seconds: float, timed_rounds) -> dict:
     program's obs attached at level ``round``; returns the stretch:
     ``rounds``, the reduced trace's ``start_ns``, ``ops``, ``modules``,
     the shifted program ``spans``, each round's [start, end] on the trace
-    clock (``round_bounds``) and the stretch's ``lo``/``hi``."""
+    clock (``round_bounds``), the stretch's ``lo``/``hi`` and its rounds
+    with a non-finite loss or accuracy (``bad``)."""
     import jax
     from repro.obs import NOOP, Obs, ObsConfig, read_events
 
@@ -182,8 +181,9 @@ def run_stretch(cell, seconds: float, timed_rounds) -> dict:
     opts.host_tracer_level = 0
     jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
-        n, _, _, _ = timed_rounds(cell, seconds, on_round=lambda ts, d: rounds.append((ts, d)),
-                                  label="stretch D (round spans, device trace)")
+        n, _, bad, _ = timed_rounds(cell, seconds,
+                                    on_round=lambda ts, d: rounds.append((ts, d)),
+                                    label="stretch D (round spans, device trace)")
     finally:
         jax.profiler.stop_trace()
         obs.close()
@@ -194,7 +194,8 @@ def run_stretch(cell, seconds: float, timed_rounds) -> dict:
     shutil.rmtree(spandir, ignore_errors=True)
     start = trace["start_ns"]
     bounds = [[ts * 1000 - start, ts * 1000 + d * 1e9 - start] for ts, d in rounds]
-    return {**trace, "rounds": n, "spans": shift(raw, start), "round_bounds": bounds, "lo": bounds[0][0], "hi": bounds[-1][1]}
+    return {**trace, "rounds": n, "bad": bad, "spans": shift(raw, start),
+            "round_bounds": bounds, "lo": bounds[0][0], "hi": bounds[-1][1]}
 
 
 def describe(stretch: dict) -> None:
@@ -221,32 +222,3 @@ def describe(stretch: dict) -> None:
         held = [f"{k} {(min(e, b) - max(s, a)) / 1e6:.3f}" for k, s, e in stretch["spans"]
                 if min(e, b) > max(s, a)]
         log(f"stretch D slowest round {(b - a) / 1e9:.4f}s, spans (ms): {', '.join(held)}")
-
-
-def _harness_frame():
-    """The harness's ``run_cell`` frame on the stack, or None."""
-    f = sys._getframe(1)
-    while f is not None:
-        if f.f_code.co_name == "run_cell" and "cell" in f.f_locals and "seconds" in f.f_locals:
-            return f
-        f = f.f_back
-    return None
-
-
-def ensure(ctx: dict):
-    """``ctx["stretch_d"]``, run once per context: the stretch, or None
-    where no harness frame is on the stack or the program cannot attach
-    its obs to a live federation."""
-    if "stretch_d" not in ctx:
-        ctx["stretch_d"] = None
-        frame = _harness_frame()
-        if frame is None:
-            return None
-        cell, seconds = frame.f_locals["cell"], frame.f_locals["seconds"]
-        if not hasattr(cell.fed, "attach_obs"):
-            log("stretch D: the program has no Federation.attach_obs; not run")
-            return None
-        stretch = run_stretch(cell, seconds * SHARE, frame.f_globals["timed_rounds"])
-        describe(stretch)
-        ctx["stretch_d"] = stretch
-    return ctx["stretch_d"]

@@ -1,8 +1,9 @@
-"""Plain reference of a pFedSOP federation over a ResNet: the model, the
-weights made from the seed, the clients' splits and per-round sampling,
-local SGD, the Gompertz blend and Sherman-Morrison step, the masked eval
-and the Eq. 13 mean.  Written from the paper's equations in plain
-``jax.numpy``; it imports nothing of the program.
+"""Plain reference of a pFedSOP federation: the clients' splits and
+per-round sampling, local SGD, the Gompertz blend and Sherman-Morrison
+step, the masked eval and the Eq. 13 mean.  Written from the paper's
+equations in plain ``jax.numpy``; it imports nothing of the program.  The
+model is the family's (``families/<family>.py``, ``reference``): its mean
+loss over a batch of bank rows and its count of right answers.
 
 ``dtype=float32`` under ``precision="highest"`` is the reference; the
 same code in ``bfloat16`` is the control that ``correct`` has to reject.
@@ -18,89 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 EVAL_ROWS = 256  # rows per eval block
-
-
-# -- weights ------------------------------------------------------------------
-
-
-def _conv_init(key, kh, kw, cin, cout):
-    return jax.random.normal(key, (kh, kw, cin, cout), jnp.float32) * np.sqrt(
-        2.0 / (kh * kw * cin))
-
-
-def _gn_init(c):
-    return {"scale": jnp.ones((c,), jnp.float32), "bias": jnp.zeros((c,), jnp.float32)}
-
-
-def init_params(key, model: dict):
-    """He-normal convs, unit GroupNorm, a 1/sqrt(fan-in) head: the layout
-    the program's ResNet reads (stem, blocks, fc)."""
-    chans = model["channels"]
-    keys = jax.random.split(key, len(chans) + 2)
-    params = {"stem": _conv_init(keys[0], 3, 3, model["in_channels"], chans[0]),
-              "stem_gn": _gn_init(chans[0])}
-    blocks, cin = [], chans[0]
-    for i, cout in enumerate(chans):
-        k1, k2, k3 = jax.random.split(keys[i + 1], 3)
-        blk = {"conv1": _conv_init(k1, 3, 3, cin, cout), "gn1": _gn_init(cout),
-               "conv2": _conv_init(k2, 3, 3, cout, cout), "gn2": _gn_init(cout)}
-        if cin != cout:
-            blk["proj"] = _conv_init(k3, 1, 1, cin, cout)
-        blocks.append(blk)
-        cin = cout
-    params["blocks"] = tuple(blocks)
-    params["fc_w"] = jax.random.normal(keys[-1], (cin, model["n_classes"]),
-                                       jnp.float32) / np.sqrt(cin)
-    params["fc_b"] = jnp.zeros((model["n_classes"],), jnp.float32)
-    return params
-
-
-def make_weights(seed: int, model: dict):
-    """The initial weights, made on the device in one jitted call."""
-    return jax.jit(lambda key: init_params(key, model))(jax.random.PRNGKey(seed))
-
-
-# -- model --------------------------------------------------------------------
-
-
-def _conv(x, w, stride=1):
-    return jax.lax.conv_general_dilated(
-        x, w, (stride, stride), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-
-def _group_norm(p, x, groups=8, eps=1e-5):
-    b, h, w, c = x.shape
-    g = min(groups, c)
-    while c % g:
-        g -= 1
-    xg = x.reshape(b, h, w, g, c // g)
-    mean = jnp.mean(xg, axis=(1, 2, 4), keepdims=True)
-    var = jnp.mean(jnp.square(xg - mean), axis=(1, 2, 4), keepdims=True)
-    x = ((xg - mean) / jnp.sqrt(var + eps)).reshape(b, h, w, c)
-    return x * p["scale"] + p["bias"]
-
-
-def forward(params, images):
-    """ResNet with GroupNorm: stem, one basic block per stage (stride 2
-    after the first, 1x1 projection where the width changes), global
-    average pool, linear head."""
-    x = jax.nn.relu(_group_norm(params["stem_gn"], _conv(images, params["stem"])))
-    for i, blk in enumerate(params["blocks"]):
-        stride = 1 if i == 0 else 2
-        h = jax.nn.relu(_group_norm(blk["gn1"], _conv(x, blk["conv1"], stride)))
-        h = _group_norm(blk["gn2"], _conv(h, blk["conv2"]))
-        if "proj" in blk:
-            x = _conv(x, blk["proj"], stride)
-        elif stride != 1:
-            x = x[:, ::stride, ::stride, :]
-        x = jax.nn.relu(h + x)
-    return jnp.mean(x, axis=(1, 2)) @ params["fc_w"] + params["fc_b"]
-
-
-def cross_entropy(params, images, labels):
-    logits = forward(params, images)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold)
 
 
 # -- clients' data -------------------------------------------------------------
@@ -150,10 +68,11 @@ def _leaves_dot(a, b):
     return sum(jnp.sum(x * y) for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
 
-def _client_round(hp: Hyper, params, delta, has_delta, global_delta, global_has,
-                  images, labels):
+def _client_round(hp: Hyper, loss, params, delta, has_delta, global_delta, global_has,
+                  batches):
     """Algorithm 1 (personalize, when both updates exist) then T steps of
-    SGD; returns (final params, new local update, mean loss)."""
+    SGD over ``batches`` (T batches of bank rows); returns (final params,
+    new local update, mean loss)."""
     dot = _leaves_dot(delta, global_delta)
     denom = jnp.sqrt(_leaves_dot(delta, delta)) * jnp.sqrt(
         _leaves_dot(global_delta, global_delta))
@@ -168,29 +87,27 @@ def _client_round(hp: Hyper, params, delta, has_delta, global_delta, global_has,
                          params, dp)                                  # Eq. 19
 
     def step(p, batch):
-        loss, g = jax.value_and_grad(cross_entropy)(p, *batch)
-        return jax.tree.map(lambda x, gi: x - hp.eta2 * gi, p, g), loss
+        value, g = jax.value_and_grad(loss)(p, batch)
+        return jax.tree.map(lambda x, gi: x - hp.eta2 * gi, p, g), value
 
-    final, losses = jax.lax.scan(step, start, (images, labels))
+    final, losses = jax.lax.scan(step, start, batches)
     new_delta = jax.tree.map(lambda a, b: (a - b) / hp.eta2, start, final)
     return final, new_delta, jnp.mean(losses)
-
-
-def _correct_rows(params, images, labels, mask):
-    hit = jnp.argmax(forward(params, images), -1) == labels
-    return jnp.sum(jnp.where(mask, hit, False))
 
 
 class ReferenceFederation:
     """Replays a federation's rounds from the seed in plain JAX.
 
+    ``bank`` is the family's sample bank (a dict of arrays with one
+    leading sample axis; floating arrays are read in ``dtype``), ``loss``
+    and ``correct`` its reference model (``families/<family>.py``).
     States live in a dict, one entry per client that has trained; the
     others hold the initial weights and a zero update."""
 
-    def __init__(self, images, labels, split: ClientSplit, init, hp: Hyper,
+    def __init__(self, bank: dict, split: ClientSplit, init, hp: Hyper,
                  kprime: int, local_iters: int, batch: int, seed: int,
-                 dtype=jnp.float32):
-        self.images, self.labels, self.split = images, labels, split
+                 loss, correct, dtype=jnp.float32):
+        self.bank, self.split = bank, split
         self.dtype = dtype
         self.init = jax.tree.map(lambda x: jnp.asarray(x, dtype), init)
         self.hp, self.kprime, self.T, self.B = hp, kprime, local_iters, batch
@@ -199,10 +116,15 @@ class ReferenceFederation:
         self.global_delta = jax.tree.map(jnp.zeros_like, self.init)
         self.global_has = False
         self._train = jax.jit(jax.vmap(
-            lambda p, d, h, gd, gh, x, y: _client_round(hp, p, d, h, gd, gh, x, y),
-            in_axes=(0, 0, 0, None, None, 0, 0)))
-        self._hits = jax.jit(_correct_rows)
+            lambda p, d, h, gd, gh, b: _client_round(hp, loss, p, d, h, gd, gh, b),
+            in_axes=(0, 0, 0, None, None, 0)))
+        self._hits = jax.jit(correct)
         self._mean = jax.jit(lambda t: jax.tree.map(lambda x: jnp.mean(x, 0), t))
+
+    def _rows(self, idx):
+        """The bank's rows ``idx`` (any shape) on the device."""
+        return {k: jnp.asarray(v[idx], self.dtype if np.issubdtype(v.dtype, np.floating)
+                               else None) for k, v in self.bank.items()}
 
     def _state(self, c):
         zero = jax.tree.map(jnp.zeros_like, self.init)
@@ -218,8 +140,7 @@ class ReferenceFederation:
             pad = np.zeros(EVAL_ROWS, np.int64)
             pad[:len(blk)] = blk
             mask = np.arange(EVAL_ROWS) < len(blk)
-            hits += int(self._hits(params, jnp.asarray(self.images[pad], self.dtype),
-                                   jnp.asarray(self.labels[pad]), jnp.asarray(mask)))
+            hits += int(self._hits(params, self._rows(pad), jnp.asarray(mask)))
         return hits / len(rows)
 
     def run_round(self) -> dict:
@@ -229,7 +150,7 @@ class ReferenceFederation:
         has = jnp.asarray([s[2] > 0 for s in states])
         final, deltas, losses = self._train(
             stack(0), stack(1), has, self.global_delta, jnp.asarray(self.global_has),
-            jnp.asarray(self.images[rows], self.dtype), jnp.asarray(self.labels[rows]))
+            self._rows(rows))
         accs = []
         for j, c in enumerate(ids):
             p = jax.tree.map(lambda x: x[j], final)
